@@ -68,6 +68,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 import numpy as np
 
 from repro.config import ThinKVConfig
@@ -819,7 +820,8 @@ def mesh_sweep_inner(devices=(1, 4, 8), arch="r1-llama-8b", requests=3,
     for d in devices:
         mesh = None
         if d > 1:
-            mesh = jax.make_mesh((d,), ("model",))
+            mesh = jax.make_mesh((d,), ("model",),
+                                 axis_types=(AxisType.Auto,))
         eng = ThinKVEngine(scfg, params=params, backend="reference",
                            mesh=mesh)
         params = eng.params
@@ -857,6 +859,8 @@ def mesh_sweep(devices=(1, 4, 8), smoke=False):
     host devices (XLA_FLAGS must be set before the first jax import, so
     the parent process cannot run the sweep itself)."""
     import sys
+    from repro.launch.mesh import refuse_on_tpu
+    refuse_on_tpu("the table2 mesh sweep")
     env = dict(os.environ)
     flag = f"--xla_force_host_platform_device_count={max(devices)}"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + flag).strip()

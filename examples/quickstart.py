@@ -28,7 +28,7 @@ print(f"NVFP4 roundtrip rel-RMSE: "
 
 # --- 2. a CT cache for a 2-layer toy model ------------------------------
 # the paged split: CTCache carries metadata + the fp TBQ buffer, PoolView
-# carries the quantized planes in paged [L, NB, BS, H, ...] layout
+# carries the quantized planes in paged [L, NB, H, BS, ...] layout
 tk = ThinKVConfig(refresh_interval=16, group_size=8, block_size=8,
                   token_budget=64, retention_schedule=(16, 8, 4),
                   min_retention=4, max_segments=64, kmeans_iters=4)

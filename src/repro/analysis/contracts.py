@@ -312,15 +312,18 @@ def audit_engine(engine,
 
 def audit_flash_prefill(seq: int = 128, heads: int = 4, kv_heads: int = 2,
                         head_dim: int = 16) -> EntryAudit:
-    """Contract audit of the standalone compiled ``flash_prefill``
-    kernel: exactly one launch, nothing host-facing."""
+    """Contract audit of the standalone ``flash_prefill`` kernel: exactly
+    one launch, nothing host-facing.  Compiled on a TPU, interpret mode
+    elsewhere."""
     import jax
     import jax.numpy as jnp
 
     from repro.kernels.flash_prefill import flash_prefill
 
+    interpret = jax.default_backend() != "tpu"
+
     def fn(q, kk, vv):
-        return flash_prefill(q, kk, vv, interpret=True)
+        return flash_prefill(q, kk, vv, interpret=interpret)
 
     q = jax.ShapeDtypeStruct((seq, heads, head_dim), jnp.float32)
     kv = jax.ShapeDtypeStruct((seq, kv_heads, head_dim), jnp.float32)

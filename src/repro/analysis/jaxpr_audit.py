@@ -34,7 +34,7 @@ from collections import Counter
 from typing import Dict, List, Tuple
 
 import numpy as np
-from jax import core as jcore
+from jax.extend import core as jcore
 
 #: Collectives that REDUCE values across shards — these change math when
 #: the mesh changes unless the operand is integer (exact) or whitelisted.
@@ -224,7 +224,9 @@ def _walk(jaxpr, census: Census, path: str) -> None:
             # the kernel body is device-internal: launch accounting stops
             # here (count_launches matches), but don't descend for the
             # host-facing checks either — a kernel can't call back out.
-            census.launch_sites.append(here)
+            kernel = eqn.params.get("name")
+            census.launch_sites.append(f"{here}({kernel})" if kernel
+                                       else here)
             continue
         if name in CALLBACK_PRIMITIVES:
             cb = eqn.params.get("callback")

@@ -5,7 +5,9 @@ thought type, segment identity, and an eviction state that lets evicted
 slots be *reused in place* by later tokens — never gather-compacted.
 
 TPU adaptations (DESIGN.md Sec. 3):
-* block size 16 == quantization group g == one (16,128) VMEM tile per head;
+* block size 16 == quantization group g; the planes keep the head axis
+  AHEAD of the block's token axis, so one block of one head is a
+  contiguous (BS, D) page — the tile the paged kernels stream;
 * "start indices + segment mask" are fused into a per-slot ``slot_seg``
   plane; the eviction mask is the per-slot ``slot_state`` plane
   (0=free, 1=valid, 2=soft-evicted/reusable);
@@ -19,8 +21,10 @@ TPU adaptations (DESIGN.md Sec. 3):
 Data model (this PR's paged refactor):
 
 * :class:`PoolView` holds the HEAVY planes (nibble codes + group scales) in
-  **paged layout** ``[L, num_blocks, block_size, H, ...]`` — the exact
-  layout the ``ct_paged_attention`` kernel streams from HBM.
+  **paged layout** ``[L, num_blocks, H, block_size, ...]`` — the exact
+  layout the ``ct_paged_attention`` kernels stream from HBM.  Logical slot
+  ``s`` of a layer lives at ``[s // BS, :, s % BS]`` (:func:`slots_take`
+  / :func:`slots_put`).
 * :class:`CTCache` holds only per-request METADATA (slot/segment state,
   thought bookkeeping) and the full-precision TBQ buffer.  Metadata planes
   stay flat ``[L, NS]`` (NS = num_blocks * block_size) because the
@@ -102,10 +106,10 @@ class PoolView(NamedTuple):
     :class:`GlobalPool` holds the same planes with ``NP`` physical blocks.
     """
 
-    k_codes: jax.Array      # [L, nb, BS, H, D] uint8
-    v_codes: jax.Array      # [L, nb, BS, H, D] uint8
-    k_scales: jax.Array     # [L, nb, BS, H, D//GROUP] bf16 (e4m3-valued)
-    v_scales: jax.Array     # [L, nb, BS, H, D//GROUP] bf16
+    k_codes: jax.Array      # [L, nb, H, BS, D] uint8
+    v_codes: jax.Array      # [L, nb, H, BS, D] uint8
+    k_scales: jax.Array     # [L, nb, H, BS, D//GROUP] bf16 (e4m3-valued)
+    v_scales: jax.Array     # [L, nb, H, BS, D//GROUP] bf16
 
 
 def init_pool_view(dims: CacheDims, num_blocks: int | None = None
@@ -114,26 +118,34 @@ def init_pool_view(dims: CacheDims, num_blocks: int | None = None
     L, BS, H, D = dims.L, dims.BS, dims.H, dims.D
     sg = dims.scale_groups
     return PoolView(
-        k_codes=jnp.zeros((L, nb, BS, H, D), jnp.uint8),
-        v_codes=jnp.zeros((L, nb, BS, H, D), jnp.uint8),
-        k_scales=jnp.zeros((L, nb, BS, H, sg), SCALE_DTYPE),
-        v_scales=jnp.zeros((L, nb, BS, H, sg), SCALE_DTYPE),
+        k_codes=jnp.zeros((L, nb, H, BS, D), jnp.uint8),
+        v_codes=jnp.zeros((L, nb, H, BS, D), jnp.uint8),
+        k_scales=jnp.zeros((L, nb, H, BS, sg), SCALE_DTYPE),
+        v_scales=jnp.zeros((L, nb, H, BS, sg), SCALE_DTYPE),
     )
 
 
-def view_flat(view: PoolView) -> Tuple[jax.Array, ...]:
-    """Paged planes -> flat [L, NS, ...] (free reshape)."""
-    def f(a):
-        L, nb, bs = a.shape[:3]
-        return a.reshape(L, nb * bs, *a.shape[3:])
-    return tuple(f(a) for a in view)
+def slots_take(plane: jax.Array, idx: jax.Array) -> jax.Array:
+    """One layer's paged plane ``[nb, H, BS, X]`` at logical slots ``idx``
+    ``[n]`` -> ``[n, H, X]``."""
+    bs = plane.shape[2]
+    return plane[idx // bs, :, idx % bs]
 
 
-def view_paged(dims: CacheDims, *flat: jax.Array) -> PoolView:
-    def p(a):
-        L = a.shape[0]
-        return a.reshape(L, -1, dims.BS, *a.shape[2:])
-    return PoolView(*(p(a) for a in flat))
+def slots_put(plane: jax.Array, idx: jax.Array, val: jax.Array
+              ) -> jax.Array:
+    """Write ``val`` ``[n, H, X]`` into logical slots ``idx`` of one
+    layer's paged plane ``[nb, H, BS, X]``."""
+    bs = plane.shape[2]
+    return plane.at[idx // bs, :, idx % bs].set(val)
+
+
+def page_tokens(pages: jax.Array) -> jax.Array:
+    """Paged planes ``[..., nb, H, BS, X]`` -> token-major
+    ``[..., nb * BS, H, X]`` (the dense readers' layout)."""
+    x = jnp.swapaxes(pages, -3, -2)
+    return x.reshape(*x.shape[:-4], x.shape[-4] * x.shape[-3],
+                     *x.shape[-2:])
 
 
 @jax.tree_util.register_pytree_node_class
@@ -246,7 +258,6 @@ def commit_group(cfg: ThinKVConfig, dims: CacheDims, cache: CTCache,
     policy = get_policy(policy)
     t = cache.cur_thought
     positions = cache.num_tokens - dims.G + jnp.arange(dims.G, dtype=jnp.int32)
-    k_codes_f, v_codes_f, k_scales_f, v_scales_f = view_flat(view)
 
     def one_layer(buf_k, buf_v, k_codes, v_codes, k_scales, v_scales,
                   slot_state, slot_seg, slot_pos, slot_bits, block_type):
@@ -256,9 +267,9 @@ def commit_group(cfg: ThinKVConfig, dims: CacheDims, cache: CTCache,
         # guard: never write through invalid addresses (ok False is a
         # capacity bug surfaced via cache_pressure metrics, not corruption)
         safe = jnp.where(ok, idx, 0)
-        upd = lambda plane, val: plane.at[safe].set(
-            jnp.where(ok.reshape((-1,) + (1,) * (val.ndim - 1)), val,
-                      plane[safe]))
+        upd = lambda plane, val: slots_put(
+            plane, safe, jnp.where(ok[:, None, None], val,
+                                   slots_take(plane, safe)))
         k_codes = upd(k_codes, kc)
         v_codes = upd(v_codes, vc)
         k_scales = upd(k_scales, ks)
@@ -281,15 +292,14 @@ def commit_group(cfg: ThinKVConfig, dims: CacheDims, cache: CTCache,
 
     outs = jax.vmap(one_layer)(
         cache.buf_k.astype(jnp.float32), cache.buf_v.astype(jnp.float32),
-        k_codes_f, v_codes_f, k_scales_f, v_scales_f,
-        cache.slot_state, cache.slot_seg, cache.slot_pos, cache.slot_bits,
-        cache.block_type)
+        *view, cache.slot_state, cache.slot_seg, cache.slot_pos,
+        cache.slot_bits, cache.block_type)
     (k_codes, v_codes, k_scales, v_scales, slot_state, slot_seg, slot_pos,
      slot_bits, block_type) = outs
     cache = cache.replace(
         slot_state=slot_state, slot_seg=slot_seg, slot_pos=slot_pos,
         slot_bits=slot_bits, block_type=block_type, buf_len=jnp.int32(0))
-    return cache, view_paged(dims, k_codes, v_codes, k_scales, v_scales)
+    return cache, PoolView(k_codes, v_codes, k_scales, v_scales)
 
 
 def commit_and_evict_if_full(cfg: ThinKVConfig, dims: CacheDims,
@@ -381,9 +391,10 @@ def _anneal_one_segment(cfg: ThinKVConfig, dims: CacheDims, seg: jax.Array,
                         axis_name: str | None = None, policy=None):
     """Anneal segment ``seg`` one retention level in ONE layer.  Returns
     updated (slot_state, seg_level_row).  ``k_codes``/``k_scales`` are the
-    layer's FLAT [NS, ...] planes (this shard's heads when ``axis_name``
-    is set — the selection keys are gathered to the FULL head set so every
-    shard makes the same eviction decision as a single device would)."""
+    layer's paged [nb, H, BS, ...] planes (this shard's heads when
+    ``axis_name`` is set — the selection keys are gathered to the FULL
+    head set so every shard makes the same eviction decision as a single
+    device would)."""
     policy = get_policy(policy)
     idx, valid = _segment_tokens(dims, slot_seg, slot_state, seg)
     level = seg_level_row[seg]
@@ -392,8 +403,8 @@ def _anneal_one_segment(cfg: ThinKVConfig, dims: CacheDims, seg: jax.Array,
     do = enable & (count > 0)
 
     # dequantized post-RoPE keys of the segment, flattened over heads
-    kc = jnp.take(k_codes, idx, axis=0)                   # [cap,H,D]
-    ks = jnp.take(k_scales, idx, axis=0)
+    kc = slots_take(k_codes, idx)                         # [cap,H,D]
+    ks = slots_take(k_scales, idx)
     bits = jnp.take(slot_bits, idx, axis=0)               # [cap]
     keys = Q.dequantize_by_bitcode(
         kc, ks.astype(jnp.float32),
@@ -429,7 +440,6 @@ def tbe_anneal_all(cfg: ThinKVConfig, dims: CacheDims, cache: CTCache,
     """Case 1: a transition segment ended — anneal every preceding segment
     (including previous transitions) one retention level, in every layer."""
     policy = get_policy(policy)
-    k_codes_f, _, k_scales_f, _ = view_flat(view)
 
     def one_layer(k_codes, k_scales, slot_state, slot_seg, slot_bits,
                   seg_level_row):
@@ -447,7 +457,7 @@ def tbe_anneal_all(cfg: ThinKVConfig, dims: CacheDims, cache: CTCache,
         return slot_state, seg_level_row
 
     slot_state, seg_level = jax.vmap(one_layer)(
-        k_codes_f, k_scales_f, cache.slot_state, cache.slot_seg,
+        view.k_codes, view.k_scales, cache.slot_state, cache.slot_seg,
         cache.slot_bits, cache.seg_level)
     slot_state, block_type = jax.vmap(
         lambda s, b: _free_empty_blocks(dims, s, b))(slot_state,
@@ -462,7 +472,6 @@ def budget_evict(cfg: ThinKVConfig, dims: CacheDims, cache: CTCache,
     """Case 2: cache above budget with no transition — anneal the oldest,
     least-important segment one level per round until within budget."""
     policy = get_policy(policy)
-    k_codes_f, _, k_scales_f, _ = view_flat(view)
 
     def one_layer(k_codes, k_scales, slot_state, slot_seg, slot_bits,
                   seg_level_row):
@@ -498,7 +507,7 @@ def budget_evict(cfg: ThinKVConfig, dims: CacheDims, cache: CTCache,
         return slot_state, seg_level_row
 
     slot_state, seg_level = jax.vmap(one_layer)(
-        k_codes_f, k_scales_f, cache.slot_state, cache.slot_seg,
+        view.k_codes, view.k_scales, cache.slot_state, cache.slot_seg,
         cache.slot_bits, cache.seg_level)
     slot_state, block_type = jax.vmap(
         lambda s, b: _free_empty_blocks(dims, s, b))(slot_state,
@@ -548,7 +557,7 @@ def refresh(cfg: ThinKVConfig, dims: CacheDims, cache: CTCache,
 class GlobalPool(NamedTuple):
     """Physical block pool shared by every request slot.
 
-    ``view`` planes are ``[L, NP, BS, ...]``; ``refcount`` is a per-layer
+    ``view`` planes are ``[L, NP, H, BS, ...]``; ``refcount`` is a per-layer
     per-physical-block REFERENCE COUNT (free ⇔ refcount 0).  Per-request
     per-layer block tables (``[L, NB]`` int32, UNMAPPED = -1) live with
     the engine; each mapped table entry holds one reference, and the
@@ -587,8 +596,8 @@ def stacked_slot_plane(dims: CacheDims, plane: jax.Array) -> jax.Array:
 
 def stacked_buffers(buf: jax.Array) -> jax.Array:
     """Engine TBQ buffers [R, L, G, H, D] -> the fused kernel's
-    [L, R, G, H, D]."""
-    return jnp.swapaxes(buf, 0, 1)
+    [L, R, H, G, D] (one head's G tokens form one (G, D) tile)."""
+    return jnp.transpose(buf, (1, 0, 3, 2, 4))
 
 
 def gather_view(pool_view: PoolView, table: jax.Array) -> PoolView:
@@ -622,8 +631,8 @@ def changed_slots(view_old: PoolView, view_new: PoolView) -> jax.Array:
     planes differ — content-based, so a write of identical bytes is not a
     mutation and needs no copy)."""
     def per(a, b):
-        L, nb, bs = a.shape[:3]
-        return jnp.any((a != b).reshape(L, nb * bs, -1), axis=-1)
+        L, nb, _, bs = a.shape[:4]
+        return jnp.any(a != b, axis=(2, 4)).reshape(L, nb * bs)
     out = per(view_old[0], view_new[0])
     for a, b in zip(view_old[1:], view_new[1:]):
         out = out | per(a, b)
@@ -835,7 +844,7 @@ def extract_request(dims: CacheDims, pool: GlobalPool, table: jax.Array
                     ) -> Tuple[PoolView, jax.Array]:
     """Snapshot a request's physical blocks for a host-side spill.
 
-    Returns the per-request paged view (``[L, NB, BS, ...]``, gathered
+    Returns the per-request paged view (``[L, NB, H, BS, ...]``, gathered
     through the table) and the ``[L, NB]`` mapped mask.  Unmapped logical
     blocks gather garbage (block 0) — harmless, because restore only
     claims and scatters the mapped entries and every slot of an unmapped
@@ -988,12 +997,10 @@ def engine_advance(cfg: ThinKVConfig, dims: CacheDims, pool: GlobalPool,
 def dequant_layer(dims: CacheDims, cache: CTCache, view: PoolView,
                   layer: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Reference read of one layer: (k, v, valid) with k/v [NS,H,D] f32."""
-    k_codes_f, v_codes_f, k_scales_f, v_scales_f = view_flat(view)
+    kc, vc, ks, vs = (page_tokens(p[layer]) for p in view)
     bits = cache.slot_bits[layer].astype(jnp.int32)[:, None, None]
-    k = Q.dequantize_by_bitcode(k_codes_f[layer],
-                                k_scales_f[layer].astype(jnp.float32), bits)
-    v = Q.dequantize_by_bitcode(v_codes_f[layer],
-                                v_scales_f[layer].astype(jnp.float32), bits)
+    k = Q.dequantize_by_bitcode(kc, ks.astype(jnp.float32), bits)
+    v = Q.dequantize_by_bitcode(vc, vs.astype(jnp.float32), bits)
     valid = cache.slot_state[layer] == VALID
     return k, v, valid
 

@@ -12,7 +12,7 @@ Couples the CT cache with the model's decode step:
 
 State is split per the paged refactor: :class:`~repro.core.ct_cache.CTCache`
 carries metadata + the TBQ buffer, :class:`~repro.core.ct_cache.PoolView`
-carries the quantized planes in paged ``[L, NB, BS, H, ...]`` layout — the
+carries the quantized planes in paged ``[L, NB, H, BS, ...]`` layout — the
 layout the Pallas kernel (`repro.kernels.ct_paged_attention`) streams.
 `decode_attention_ref` here is the pure-jnp oracle the kernel is validated
 against and the CPU fallback.

@@ -16,7 +16,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -92,4 +92,4 @@ def make_cross_pod_grad_fn(loss_fn, mesh, *, compress: bool = True):
         grad_one_pod, mesh=mesh,
         in_specs=(pspec, P("pod"), pspec),
         out_specs=(pspec, pspec),
-        check_rep=False)
+        check_vma=False)

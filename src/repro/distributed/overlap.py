@@ -17,7 +17,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -77,4 +77,4 @@ def overlapped_moe_ffn(x: jax.Array, w_up: jax.Array, w_down: jax.Array,
 
     return shard_map(local, mesh=mesh,
                      in_specs=(P(axis), P(axis), P(axis)),
-                     out_specs=P(axis), check_rep=False)(x, w_up, w_down)
+                     out_specs=P(axis), check_vma=False)(x, w_up, w_down)

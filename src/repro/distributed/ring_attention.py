@@ -24,7 +24,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
@@ -100,7 +100,7 @@ def ring_attention(q, k, v, mesh, axis: str = "model"):
         local, mesh=mesh,
         in_specs=(spec,) * 3,
         out_specs=spec,
-        check_rep=False)(q, k, v)
+        check_vma=False)(q, k, v)
 
 
 def _axes_size(mesh, axes) -> int:

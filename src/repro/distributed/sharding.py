@@ -203,7 +203,7 @@ def to_shardings(specs, mesh: Mesh):
 # serving-engine specs: tensor-parallel sharding of the ThinKV global pool
 # ---------------------------------------------------------------------------
 # The serving engine shards on the KV-HEAD axis of the paged planes
-# ([L, NP, BS, H, ...] — axis 3) via shard_map: attention is embarrassingly
+# ([L, NP, H, BS, ...] — axis 2) via shard_map: attention is embarrassingly
 # parallel over heads, so per-shard math is bit-identical to a slice of the
 # single-device run and only the attention OUTPUT rejoins the replicated
 # residual stream (all-gather, pure data movement).  Everything head-
@@ -216,13 +216,13 @@ def to_shardings(specs, mesh: Mesh):
 # while GQA serving configs keep kv_heads % |model| == 0.)
 
 SERVE_HEAD_AXIS = "model"          # mesh axis the KV-head dim shards over
-_PLANE_HEAD_DIM = 3                # [L, NP, BS, H, ...]
+_PLANE_HEAD_DIM = 2                # [L, NP, H, BS, ...]
 _BUF_HEAD_DIM = 2                  # per-request TBQ buffer [L, G, H, D]
 
 
 def serve_plane_spec() -> P:
-    """Pool / per-request paged planes ``[L, nb, BS, H, ...]``."""
-    return P(None, None, None, SERVE_HEAD_AXIS)
+    """Pool / per-request paged planes ``[L, nb, H, BS, ...]``."""
+    return P(*([None] * _PLANE_HEAD_DIM), SERVE_HEAD_AXIS)
 
 
 def serve_buf_spec(batched: bool) -> P:

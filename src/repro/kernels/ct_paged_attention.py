@@ -4,7 +4,7 @@ Thinking', adapted per DESIGN.md Sec. 3).
 The FUSED entry point (``ct_paged_attention_fused``) serves a whole
 continuous-batching decode tick in ONE launch: the grid is
 ``(L, R, H, NB + 1)`` — a leading layer axis over the pool planes (which
-already carry ``[L, NP, BS, H, ...]``), then request slots, kv heads, and
+already carry ``[L, NP, H, BS, ...]``), then request slots, kv heads, and
 the per-sequence block walk.  The first ``NB`` steps of the last grid axis
 stream quantized pool blocks through the block-table indirection; the final
 step attends the full-precision TBQ buffer ``B_buf`` for the same
@@ -53,8 +53,13 @@ corresponding slice of the full launch, the grid shrinks to
 The head count is a plain grid extent with no tiling constraint, so any
 ``H % num_shards == 0`` split compiles unchanged.
 
-Tiling: a KV block is (block_size=16, head_dim=128) per head — exactly one
-TPU (16,128) tile; codes are uint8 lanes, scales one bf16 (16,8) tile.
+Tiling: planes are ``[..., H, BS, D]``, so one block of one head is a
+(block_size=16, head_dim=128) page whose block shape equals the array's
+last two dims; codes are uint8, scales a bf16 (16, D/g) page.  Per-slot
+metadata enters as ``[..., 1, BS]`` (state) and ``[..., BS, 1]`` (bits)
+blocks, one per page, cast to int32 in-kernel — Mosaic accepts neither a
+dynamic sublane offset into a uint8 tile nor 1-D uint8 vectors.  The
+kernels compile for a v5e at block_size 16 (``tests/test_tpu_compile.py``).
 
 Validated on CPU against ``ref.ct_paged_attention_fused_ref`` /
 ``ref.ct_paged_attention_ref`` in interpret mode (``tests/test_kernels.py``
@@ -74,9 +79,14 @@ NEG_INF = -1e30
 VALID = 1
 
 
-def _decode_codes(codes_u8, bits_u8, scales, group: int):
-    """Fused in-VMEM dequant: [BS,D] uint8 codes -> f32, per-slot bit width
-    in {2,4,8}, E4M3-valued scales [BS, D//group]."""
+def _decode_codes(codes_u8, bits, scales, group: int):
+    """Fused in-VMEM dequant of one page: [BS, D] uint8 codes -> f32.
+
+    ``bits`` [BS, 1] int32 is the per-slot width in {2, 4, 8};
+    ``scales`` [BS, D//group] holds E4M3-valued group scales.  The scales
+    widen to [BS, D] through a 0/1 [D//group, D] matmul: Mosaic cannot
+    reshape the lane axis into (D//group, group), and a bf16 x {0, 1}
+    product summed once is exact."""
     c = codes_u8.astype(jnp.int32)
     # ternary (2b): low 2 bits; {0:+0, 1:+1, 3:-1}
     c2 = c & 3
@@ -91,57 +101,72 @@ def _decode_codes(codes_u8, bits_u8, scales, group: int):
                           (1.0 + 0.5 * man) * jnp.exp2(exp - 1.0))
     # int8 (8b): two's complement
     v8 = jnp.where(c >= 128, c - 256, c).astype(jnp.float32)
-    bits = bits_u8.astype(jnp.int32)[:, None]
     vals = jnp.where(bits == 2, v2, jnp.where(bits == 4, v4, v8))
-    bs, d = vals.shape
-    vg = vals.reshape(bs, d // group, group)
-    out = vg * scales.astype(jnp.float32)[:, :, None]
-    return out.reshape(bs, d)
+    ng, d = scales.shape[-1], vals.shape[-1]
+    expand = (jax.lax.broadcasted_iota(jnp.int32, (ng, d), 1) // group ==
+              jax.lax.broadcasted_iota(jnp.int32, (ng, d), 0))
+    full = jax.lax.dot_general(
+        scales.astype(jnp.bfloat16), expand.astype(jnp.bfloat16),
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    return vals * full
+
+
+def _page_attention(q, kc_ref, vc_ref, ks_ref, vs_ref, state_ref, bits_ref,
+                    group: int, accumulate):
+    """Attend one pool page: dequantize its K/V, score, and hand the
+    partition to ``accumulate``.  Every ref holds exactly one page of one
+    head (leading block dims are 1)."""
+    lead = (0,) * (kc_ref.ndim - 2)
+    bits = bits_ref[lead].astype(jnp.int32)                # [BS, 1]
+    state = state_ref[lead].astype(jnp.int32)              # [1, BS]
+    k = _decode_codes(kc_ref[lead], bits, ks_ref[lead], group)   # [BS, D]
+    v = _decode_codes(vc_ref[lead], bits, vs_ref[lead], group)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    accumulate(s * (1.0 / (q.shape[-1] ** 0.5)), state == VALID, v)
+
+
+def _flash_update(m_ref, l_ref, acc_ref, s, valid, v):
+    """Online-softmax update of the (m, l, acc) scratch with one
+    partition: scores ``s`` [GQ, N], ``valid`` broadcastable to it,
+    values ``v`` [N, D]."""
+    s = jnp.where(valid, s, NEG_INF)
+    m_prev, l_prev = m_ref[...], l_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    p = jnp.where(valid, p, 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_ref[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
+
+
+def _init_scratch(m_ref, l_ref, acc_ref):
+    acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
+    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, m_ref.dtype)
+    l_ref[...] = jnp.zeros(l_ref.shape, l_ref.dtype)
 
 
 def _kernel(block_table, q_ref, kc_ref, vc_ref, ks_ref, vs_ref, state_ref,
-            bits_ref, o_ref, m_ref, l_ref, acc_ref, *, group: int,
-            blocks_per_seq: int):
+            bits_ref, o_ref, mo_ref, lo_ref, m_ref, l_ref, acc_ref, *,
+            group: int, blocks_per_seq: int):
     b = pl.program_id(2)
 
     @pl.when(b == 0)
     def _init():
-        acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
-        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, m_ref.dtype)
-        l_ref[...] = jnp.zeros(l_ref.shape, l_ref.dtype)
+        _init_scratch(m_ref, l_ref, acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)                    # [GQ, D]
-    kc = kc_ref[0, :, 0]                                   # [BS, D] u8
-    vc = vc_ref[0, :, 0]
-    ks = ks_ref[0, :, 0]                                   # [BS, D//g]
-    vs = vs_ref[0, :, 0]
-    state = state_ref[0, 0]                                # [BS]
-    bits = bits_ref[0, 0]
-
-    k = _decode_codes(kc, bits, ks, group)                 # [BS, D]
-    v = _decode_codes(vc, bits, vs, group)
-
-    d = q.shape[-1]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    s = s * (1.0 / (d ** 0.5))                             # [GQ, BS]
-    valid = (state == VALID)
-    s = jnp.where(valid[None, :], s, NEG_INF)
-
-    m_prev, l_prev = m_ref[0, 0], l_ref[0, 0]              # [GQ, 1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    p = jnp.where(valid[None, :], p, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[0, 0] = m_new
-    l_ref[0, 0] = l_new
+    _page_attention(q, kc_ref, vc_ref, ks_ref, vs_ref, state_ref, bits_ref,
+                    group, functools.partial(_flash_update, m_ref, l_ref,
+                                             acc_ref))
 
     @pl.when(b == blocks_per_seq - 1)
     def _final():
-        o_ref[0, 0] = acc_ref[...] / jnp.maximum(l_ref[0, 0], 1e-30)
+        o_ref[0, 0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        mo_ref[0, 0] = m_ref[...]
+        lo_ref[0, 0] = l_ref[...]
 
 
 def _fused_kernel(bt_ref, blen_ref, q_ref, kc_ref, vc_ref, ks_ref, vs_ref,
@@ -151,53 +176,37 @@ def _fused_kernel(bt_ref, blen_ref, q_ref, kc_ref, vc_ref, ks_ref, vs_ref,
     the fp TBQ buffer as the final grid step, final output from scratch."""
     rr = pl.program_id(1)
     b = pl.program_id(3)
+    accumulate = functools.partial(_flash_update, m_ref, l_ref, acc_ref)
 
     @pl.when(b == 0)
     def _init():
-        acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
-        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, m_ref.dtype)
-        l_ref[...] = jnp.zeros(l_ref.shape, l_ref.dtype)
+        _init_scratch(m_ref, l_ref, acc_ref)
 
     q = q_ref[0, 0, 0].astype(jnp.float32)                 # [GQ, D]
-    scale = 1.0 / (q.shape[-1] ** 0.5)
-
-    def accumulate(s, valid, v):
-        """Online-softmax update of (m, l, acc) with one partition."""
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(valid, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
 
     @pl.when(b < blocks_per_seq)
     def _pool_block():
-        kc = kc_ref[0, 0, :, 0]                            # [BS, D] u8
-        vc = vc_ref[0, 0, :, 0]
-        ks = ks_ref[0, 0, :, 0]                            # [BS, D//g]
-        vs = vs_ref[0, 0, :, 0]
-        state = state_ref[0, 0, 0]                         # [BS]
-        bits = bits_ref[0, 0, 0]
-        k = _decode_codes(kc, bits, ks, group)             # [BS, D]
-        v = _decode_codes(vc, bits, vs, group)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        accumulate(s, (state == VALID)[None, :], v)
+        _page_attention(q, kc_ref, vc_ref, ks_ref, vs_ref, state_ref,
+                        bits_ref, group, accumulate)
 
     @pl.when(b == blocks_per_seq)
     def _buffer_and_final():
-        bk = bk_ref[0, 0, :, 0].astype(jnp.float32)        # [G, D]
-        bv = bv_ref[0, 0, :, 0].astype(jnp.float32)
+        bk = bk_ref[0, 0, 0].astype(jnp.float32)           # [G, D]
+        bv = bv_ref[0, 0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, bk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+                                preferred_element_type=jnp.float32)
         pos = jax.lax.broadcasted_iota(jnp.int32, (1, bk.shape[0]), 1)
-        accumulate(s, pos < blen_ref[rr], bv)
+        accumulate(s * (1.0 / (q.shape[-1] ** 0.5)), pos < blen_ref[rr], bv)
         o_ref[0, 0, 0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+
+
+def _metadata_blocks(slot_state, slot_bits):
+    """[..., NB, BS] uint8 metadata -> state [..., NB, 1, BS] and bits
+    [..., NB, BS, 1] (free reshapes).  Each page then reads its own
+    block, whose last two dims equal the array's: the tiling check
+    passes at BS=16, no dynamic sublane offset into a uint8 tile is
+    needed, and no 1-D vector reaches Mosaic."""
+    return slot_state[..., None, :], slot_bits[..., None]
 
 
 @functools.partial(jax.jit, static_argnames=("group", "interpret"))
@@ -213,16 +222,16 @@ def ct_paged_attention_fused(qh: jax.Array, k_codes: jax.Array,
 
     Args:
       qh:         [L, R, H, GQ, D]   queries per layer/slot/kv-head.
-      k_codes:    [L, NP, BS, H, D]  uint8 shared physical pool planes.
-      v_codes:    [L, NP, BS, H, D]
-      k_scales:   [L, NP, BS, H, D//group]  (bf16, E4M3-valued)
-      v_scales:   [L, NP, BS, H, D//group]
+      k_codes:    [L, NP, H, BS, D]  uint8 shared physical pool planes.
+      v_codes:    [L, NP, H, BS, D]
+      k_scales:   [L, NP, H, BS, D//group]  (bf16, E4M3-valued)
+      v_scales:   [L, NP, H, BS, D//group]
       slot_state: [L, R, NB, BS]     uint8 per-request logical (1 == valid).
       slot_bits:  [L, R, NB, BS]     uint8 in {2,4,8}.
       block_table:[R, L, NB]         int32 RAW logical -> physical block
                   (-1 == unmapped; clamped here — unmapped slots are FREE).
-      buf_k:      [L, R, G, H, D]    full-precision TBQ buffer keys.
-      buf_v:      [L, R, G, H, D]
+      buf_k:      [L, R, H, G, D]    full-precision TBQ buffer keys.
+      buf_v:      [L, R, H, G, D]
       buf_len:    [R]                int32 valid buffer tokens per slot.
 
     Returns:
@@ -230,54 +239,52 @@ def ct_paged_attention_fused(qh: jax.Array, k_codes: jax.Array,
       partitions merged in-kernel; no (m, l) stats plumbing).
     """
     L, r, h, gq, d = qh.shape
-    bs = k_codes.shape[2]
+    bs = k_codes.shape[3]
     nb = block_table.shape[-1]
-    g = buf_k.shape[2]
+    g = buf_k.shape[3]
+    ng = k_scales.shape[-1]
     table = jnp.maximum(block_table, 0).astype(jnp.int32)
     blen = buf_len.astype(jnp.int32)
+    state, bits = _metadata_blocks(slot_state, slot_bits)
 
     grid = (L, r, h, nb + 1)
     kern = functools.partial(_fused_kernel, group=group, blocks_per_seq=nb)
 
     def pool_idx(ll, rr, hh, b, bt, bl):
-        return (ll, bt[rr, ll, jnp.minimum(b, nb - 1)], 0, hh, 0)
+        return (ll, bt[rr, ll, jnp.minimum(b, nb - 1)], hh, 0, 0)
 
     def meta_idx(ll, rr, hh, b, bt, bl):
-        return (ll, rr, jnp.minimum(b, nb - 1), 0)
+        return (ll, rr, jnp.minimum(b, nb - 1), 0, 0)
+
+    def head_idx(ll, rr, hh, b, bt, bl):
+        return (ll, rr, hh, 0, 0)
 
     out = pl.pallas_call(
         kern,
+        name="ct_paged_attention_fused",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, 1, gq, d),
-                             lambda ll, rr, hh, b, bt, bl:
-                                 (ll, rr, hh, 0, 0)),
-                pl.BlockSpec((1, 1, bs, 1, d), pool_idx),
-                pl.BlockSpec((1, 1, bs, 1, d), pool_idx),
-                pl.BlockSpec((1, 1, bs, 1, d // group), pool_idx),
-                pl.BlockSpec((1, 1, bs, 1, d // group), pool_idx),
-                pl.BlockSpec((1, 1, 1, bs), meta_idx),
-                pl.BlockSpec((1, 1, 1, bs), meta_idx),
-                pl.BlockSpec((1, 1, g, 1, d),
-                             lambda ll, rr, hh, b, bt, bl:
-                                 (ll, rr, 0, hh, 0)),
-                pl.BlockSpec((1, 1, g, 1, d),
-                             lambda ll, rr, hh, b, bt, bl:
-                                 (ll, rr, 0, hh, 0)),
+                pl.BlockSpec((1, 1, 1, gq, d), head_idx),
+                pl.BlockSpec((1, 1, 1, bs, d), pool_idx),
+                pl.BlockSpec((1, 1, 1, bs, d), pool_idx),
+                pl.BlockSpec((1, 1, 1, bs, ng), pool_idx),
+                pl.BlockSpec((1, 1, 1, bs, ng), pool_idx),
+                pl.BlockSpec((1, 1, 1, 1, bs), meta_idx),
+                pl.BlockSpec((1, 1, 1, bs, 1), meta_idx),
+                pl.BlockSpec((1, 1, 1, g, d), head_idx),
+                pl.BlockSpec((1, 1, 1, g, d), head_idx),
             ],
-            out_specs=pl.BlockSpec((1, 1, 1, gq, d),
-                                   lambda ll, rr, hh, b, bt, bl:
-                                       (ll, rr, hh, 0, 0)),
+            out_specs=pl.BlockSpec((1, 1, 1, gq, d), head_idx),
             scratch_shapes=[pltpu.VMEM((gq, 1), jnp.float32),
                             pltpu.VMEM((gq, 1), jnp.float32),
                             pltpu.VMEM((gq, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((L, r, h, gq, d), jnp.float32),
         interpret=interpret,
-    )(table, blen, qh, k_codes, v_codes, k_scales, v_scales, slot_state,
-      slot_bits, buf_k, buf_v)
+    )(table, blen, qh, k_codes, v_codes, k_scales, v_scales, state, bits,
+      buf_k, buf_v)
     return out
 
 
@@ -293,10 +300,10 @@ def ct_paged_attention_batched(qh: jax.Array, k_codes: jax.Array,
 
     Args:
       qh:         [R, H, GQ, D]  queries per kv head (post-RoPE).
-      k_codes:    [NP, BS, H, D] uint8 physical pool planes.
-      v_codes:    [NP, BS, H, D]
-      k_scales:   [NP, BS, H, D//group]  (bf16, E4M3-valued)
-      v_scales:   [NP, BS, H, D//group]
+      k_codes:    [NP, H, BS, D] uint8 physical pool planes.
+      v_codes:    [NP, H, BS, D]
+      k_scales:   [NP, H, BS, D//group]  (bf16, E4M3-valued)
+      v_scales:   [NP, H, BS, D//group]
       slot_state: [R, NB, BS]    uint8 per-request logical (1 == valid).
       slot_bits:  [R, NB, BS]    uint8 in {2,4,8}.
       block_table:[R, NB]        int32 RAW logical -> physical block
@@ -307,38 +314,48 @@ def ct_paged_attention_batched(qh: jax.Array, k_codes: jax.Array,
       for merging with the B_buf attention.
     """
     r, h, gq, d = qh.shape
-    npool, bs, hp, _ = k_codes.shape
+    _, hp, bs, _ = k_codes.shape
     assert hp == h, (hp, h)
+    ng = k_scales.shape[-1]
     nb = block_table.shape[-1]
     block_table = jnp.maximum(block_table, 0).astype(jnp.int32)
+    state, bits = _metadata_blocks(slot_state, slot_bits)
 
     grid = (r, h, nb)
     kern = functools.partial(_kernel, group=group, blocks_per_seq=nb)
 
+    def pool_idx(rr, hh, b, bt):
+        return (bt[rr, b], hh, 0, 0)
+
+    def meta_idx(rr, hh, b, bt):
+        return (rr, b, 0, 0)
+
+    def head_idx(rr, hh, b, bt):
+        return (rr, hh, 0, 0)
+
     out, m, l = pl.pallas_call(
         kern,
+        name="ct_paged_attention_batched",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, gq, d), lambda rr, hh, b, bt: (rr, hh, 0, 0)),
-                pl.BlockSpec((1, bs, 1, d),
-                             lambda rr, hh, b, bt: (bt[rr, b], 0, hh, 0)),
-                pl.BlockSpec((1, bs, 1, d),
-                             lambda rr, hh, b, bt: (bt[rr, b], 0, hh, 0)),
-                pl.BlockSpec((1, bs, 1, d // group),
-                             lambda rr, hh, b, bt: (bt[rr, b], 0, hh, 0)),
-                pl.BlockSpec((1, bs, 1, d // group),
-                             lambda rr, hh, b, bt: (bt[rr, b], 0, hh, 0)),
-                pl.BlockSpec((1, 1, bs), lambda rr, hh, b, bt: (rr, b, 0)),
-                pl.BlockSpec((1, 1, bs), lambda rr, hh, b, bt: (rr, b, 0)),
+                pl.BlockSpec((1, 1, gq, d), head_idx),
+                pl.BlockSpec((1, 1, bs, d), pool_idx),
+                pl.BlockSpec((1, 1, bs, d), pool_idx),
+                pl.BlockSpec((1, 1, bs, ng), pool_idx),
+                pl.BlockSpec((1, 1, bs, ng), pool_idx),
+                pl.BlockSpec((1, 1, 1, bs), meta_idx),
+                pl.BlockSpec((1, 1, bs, 1), meta_idx),
             ],
             out_specs=[
-                pl.BlockSpec((1, 1, gq, d), lambda rr, hh, b, bt: (rr, hh, 0, 0)),
-                pl.BlockSpec((1, 1, gq, 1), lambda rr, hh, b, bt: (rr, hh, 0, 0)),
-                pl.BlockSpec((1, 1, gq, 1), lambda rr, hh, b, bt: (rr, hh, 0, 0)),
+                pl.BlockSpec((1, 1, gq, d), head_idx),
+                pl.BlockSpec((1, 1, gq, 1), head_idx),
+                pl.BlockSpec((1, 1, gq, 1), head_idx),
             ],
-            scratch_shapes=[pltpu.VMEM((gq, d), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((gq, 1), jnp.float32),
+                            pltpu.VMEM((gq, 1), jnp.float32),
+                            pltpu.VMEM((gq, d), jnp.float32)],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((r, h, gq, d), jnp.float32),
@@ -346,8 +363,7 @@ def ct_paged_attention_batched(qh: jax.Array, k_codes: jax.Array,
             jax.ShapeDtypeStruct((r, h, gq, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(block_table, qh, k_codes, v_codes, k_scales, v_scales, slot_state,
-      slot_bits)
+    )(block_table, qh, k_codes, v_codes, k_scales, v_scales, state, bits)
     return out, m, l
 
 
@@ -362,7 +378,7 @@ def ct_paged_attention(q: jax.Array, k_codes: jax.Array, v_codes: jax.Array,
 
     Args:
       q:          [Hq, D]        current query (post-RoPE).
-      k_codes/v_codes/k_scales/v_scales: [NP, BS, H, ...] pool planes.
+      k_codes/v_codes/k_scales/v_scales: [NP, H, BS, ...] pool planes.
       slot_state/slot_bits: [NP, BS] PHYSICAL-layout metadata (legacy
                   single-request convention: gathered through the table
                   here so the batched kernel sees the logical view).
@@ -373,7 +389,7 @@ def ct_paged_attention(q: jax.Array, k_codes: jax.Array, v_codes: jax.Array,
       out [Hq, D] f32, m [H, Gq, 1], l [H, Gq, 1].
     """
     hq, d = q.shape
-    h = k_codes.shape[2]
+    h = k_codes.shape[1]
     gq = hq // h
     qh = q.reshape(1, h, gq, d)
     safe = jnp.maximum(block_table, 0)

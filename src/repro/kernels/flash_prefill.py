@@ -120,6 +120,7 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
         s_spec = pl.BlockSpec((1, bq, 1), lambda hh, qi, ki: (hh, qi, 0))
         out, m, l = pl.pallas_call(
             functools.partial(_kernel_stats, **kw),
+            name="flash_prefill",
             grid=grid,
             in_specs=in_specs,
             out_specs=[o_spec, s_spec, s_spec],
@@ -135,6 +136,7 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
                 jnp.swapaxes(l, 0, 1))
     out = pl.pallas_call(
         functools.partial(_kernel, **kw),
+        name="flash_prefill",
         grid=grid,
         in_specs=in_specs,
         out_specs=o_spec,

@@ -52,7 +52,7 @@ def paged_decode_attention_batched(qh, k_codes, v_codes, k_scales, v_scales,
     """Batched CT paged attention over the SHARED physical pool: one launch
     per layer for every request slot of a continuous-batching tick.
 
-    qh [R, H, GQ, D]; planes [NP, BS, H, ...]; slot_state/slot_bits
+    qh [R, H, GQ, D]; planes [NP, H, BS, ...]; slot_state/slot_bits
     [R, NB, BS] logical; block_table [R, NB] RAW (-1 == unmapped; clamped
     by the entry points — their slots are FREE so the state mask zeroes
     their contribution).
@@ -75,9 +75,9 @@ def paged_decode_attention_fused(qh, k_codes, v_codes, k_scales, v_scales,
     """A whole decode tick's attention in ONE kernel launch: every layer and
     request slot, quantized pool ∪ fp TBQ buffer merged in VMEM.
 
-    qh [L, R, H, GQ, D]; planes [L, NP, BS, H, ...]; slot_state/slot_bits
+    qh [L, R, H, GQ, D]; planes [L, NP, H, BS, ...]; slot_state/slot_bits
     [L, R, NB, BS]; block_table [R, L, NB] RAW (-1 accepted); buf_k/buf_v
-    [L, R, G, H, D]; buf_len [R].  Returns FINAL out [L, R, H, GQ, D].
+    [L, R, H, G, D]; buf_len [R].  Returns FINAL out [L, R, H, GQ, D].
     """
     use, interp = _use_pallas(force)
     if use:
@@ -190,15 +190,13 @@ def tbq_group_quant(x, bits: int, group: int = 16, *,
     return codes, scales.astype(jnp.bfloat16)
 
 
-def prefill_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                      force: Optional[str] = None):
-    """Blocked causal attention for prefill.  q [S,Hq,D], k/v [S,H,D]."""
-    use, interp = _use_pallas(force)
-    s_len = q.shape[0]
-    if use and s_len % 128 == 0:
-        return flash_prefill(q, k, v, causal=causal, window=window,
-                             interpret=interp)
-    return R.flash_prefill_ref(q, k, v, causal=causal, window=window)
+def _check_prefill_tile(s_len: int) -> None:
+    """The kernel path never drops to the oracle in silence: a chunk that
+    is not a whole number of 128-token tiles is a caller bug."""
+    if s_len % 128:
+        raise ValueError(
+            f"flash_prefill needs a 128-multiple chunk, got {s_len} tokens "
+            f"(padded chunks pass kv_valid and take the reference path)")
 
 
 def prefill_attention_stats(q, k, v, *, causal: bool = True, window: int = 0,
@@ -206,11 +204,13 @@ def prefill_attention_stats(q, k, v, *, causal: bool = True, window: int = 0,
     """Prefill attention with per-query flash stats (m, l) [S, Hq, 1] —
     the chunk partition of the chunked-prefill path; merged against the
     paged-pool partition by the engine.  ``kv_valid`` masks padded kv
-    positions (ref path only; the kernel path requires unpadded chunks).
+    positions: a padded chunk (the engine's g-sized tail) always takes
+    the reference oracle.  An unpadded chunk on the kernel path must be
+    a 128-multiple, or this raises.
     """
     use, interp = _use_pallas(force)
-    s_len = q.shape[0]
-    if use and kv_valid is None and s_len % 128 == 0:
+    if use and kv_valid is None:
+        _check_prefill_tile(q.shape[0])
         return flash_prefill(q, k, v, causal=causal, window=window,
                              interpret=interp, return_stats=True)
     return R.flash_prefill_stats_ref(q, k, v, causal=causal, window=window,

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import quantization as Q
+from repro.core.ct_cache import page_tokens
 
 NEG_INF = -1e30
 VALID = 1
@@ -24,27 +25,23 @@ def ct_paged_attention_batched_ref(qh, k_codes, v_codes, k_scales, v_scales,
     """Oracle for
     :func:`repro.kernels.ct_paged_attention.ct_paged_attention_batched`.
 
-    qh [R, H, GQ, D]; code/scale planes [NP, BS, H, ...] (shared pool);
+    qh [R, H, GQ, D]; code/scale planes [NP, H, BS, ...] (shared pool);
     slot_state/slot_bits [R, NB, BS] logical; block_table [R, NB] RAW
     (-1 == unmapped; clamped here — unmapped slots are FREE).
     """
     r, h, gq, d = qh.shape
-    _, bs = k_codes.shape[0], k_codes.shape[1]
     block_table = jnp.maximum(block_table, 0)
 
     def one(qh_r, state_r, bits_r, table_r):
-        take = lambda a: jnp.take(a, table_r, axis=0)
+        take = lambda a: page_tokens(jnp.take(a, table_r, axis=0))
         kc, vc = take(k_codes), take(v_codes)
         ks, vs = take(k_scales), take(v_scales)
-        nb = table_r.shape[0]
-        n = nb * bs
-        flat = lambda a: a.reshape(n, *a.shape[2:])
-        bits_n = flat(bits_r).astype(jnp.int32)[:, None, None]
-        k = Q.dequantize_by_bitcode(flat(kc), flat(ks).astype(jnp.float32),
+        bits_n = bits_r.reshape(-1).astype(jnp.int32)[:, None, None]
+        k = Q.dequantize_by_bitcode(kc, ks.astype(jnp.float32),
                                     bits_n, g=group)       # [n,H,D]
-        v = Q.dequantize_by_bitcode(flat(vc), flat(vs).astype(jnp.float32),
+        v = Q.dequantize_by_bitcode(vc, vs.astype(jnp.float32),
                                     bits_n, g=group)
-        valid = flat(state_r) == VALID                      # [n]
+        valid = state_r.reshape(-1) == VALID                # [n]
         s = jnp.einsum("hgd,nhd->hgn", qh_r.astype(jnp.float32), k)
         s = s / jnp.sqrt(float(d))
         s = jnp.where(valid[None, None, :], s, NEG_INF)
@@ -65,7 +62,7 @@ def ct_paged_attention_ref(q, k_codes, v_codes, k_scales, v_scales,
     """Oracle for :func:`repro.kernels.ct_paged_attention.ct_paged_attention`
     (single request; slot_state/slot_bits in PHYSICAL [NP, BS] layout)."""
     hq, d = q.shape
-    h = k_codes.shape[2]
+    h = k_codes.shape[1]
     gq = hq // h
     qh = q.reshape(1, h, gq, d)
     safe = jnp.maximum(block_table, 0)
@@ -114,16 +111,17 @@ def ct_paged_attention_fused_ref(qh, k_codes, v_codes, k_scales, v_scales,
     :func:`repro.kernels.ct_paged_attention.ct_paged_attention_fused`:
     per-layer batched pool attention flash-merged with the fp TBQ buffer.
 
-    qh [L, R, H, GQ, D]; planes [L, NP, BS, H, ...]; slot_state/slot_bits
+    qh [L, R, H, GQ, D]; planes [L, NP, H, BS, ...]; slot_state/slot_bits
     [L, R, NB, BS]; block_table [R, L, NB] RAW (-1 accepted);
-    buf_k/buf_v [L, R, G, H, D]; buf_len [R].  Returns [L, R, H, GQ, D].
+    buf_k/buf_v [L, R, H, G, D]; buf_len [R].  Returns [L, R, H, GQ, D].
     """
     def one_layer(qh_l, kc, vc, ks, vs, state_l, bits_l, table_l, bk_l,
                   bv_l):
         out_p, m_p, l_p = ct_paged_attention_batched_ref(
             qh_l, kc, vc, ks, vs, state_l, bits_l, table_l, group=group)
-        out_b, m_b, l_b = buffer_attention_batched_ref(qh_l, bk_l, bv_l,
-                                                       buf_len)
+        out_b, m_b, l_b = buffer_attention_batched_ref(
+            qh_l, jnp.swapaxes(bk_l, 1, 2), jnp.swapaxes(bv_l, 1, 2),
+            buf_len)
         return jax.vmap(merge_flash_ref)(out_p, m_p, l_p, out_b, m_b, l_b)
 
     return jax.vmap(one_layer, in_axes=(0, 0, 0, 0, 0, 0, 0, 1, 0, 0))(

@@ -211,6 +211,8 @@ def main(argv=None) -> int:
         report = {"ok": True, "matrix": [], "reports": [_run_cells(args)]}
     else:
         # parent: one subprocess per device count, merged report
+        from repro.launch.mesh import refuse_on_tpu
+        refuse_on_tpu("python -m repro.launch.audit --devices a,b")
         report = {"ok": True, "matrix": device_counts, "reports": []}
         for want in device_counts:
             env = dict(os.environ)

@@ -8,6 +8,7 @@ device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.config import MeshConfig
 
@@ -17,7 +18,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: (2, 16, 16) = 512 chips, axes (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_config(*, multi_pod: bool = False) -> MeshConfig:
@@ -29,7 +30,8 @@ def mesh_config(*, multi_pod: bool = False) -> MeshConfig:
 
 def make_mesh(cfg: MeshConfig):
     """Mesh for an arbitrary MeshConfig (tests use small CPU meshes)."""
-    return jax.make_mesh(cfg.shape, cfg.axis_names)
+    return jax.make_mesh(cfg.shape, cfg.axis_names,
+                         axis_types=(AxisType.Auto,) * len(cfg.shape))
 
 
 def parse_mesh_spec(spec: str) -> MeshConfig:
@@ -47,6 +49,19 @@ def parse_mesh_spec(spec: str) -> MeshConfig:
         names.append(name)
         shape.append(int(n))
     return MeshConfig(shape=tuple(shape), axis_names=tuple(names))
+
+
+def refuse_on_tpu(tool: str) -> None:
+    """Stop a CPU tool that re-execs children with forced host devices
+    when this process runs on a TPU: the process then holds the chip, and
+    a child that reaches for it fails or hangs on the chip lock.  Exits
+    with a message instead (``JAX_PLATFORMS=cpu`` runs the tool on the
+    host CPU)."""
+    if jax.default_backend() == "tpu":
+        raise SystemExit(
+            f"{tool} is a CPU tool: it re-execs child processes with "
+            f"forced host devices, and on a TPU this process holds the "
+            f"chip they would need.  Run it with JAX_PLATFORMS=cpu.")
 
 
 def make_serve_mesh(spec: str):

@@ -259,6 +259,8 @@ def main():
                  "the orchestrator's finish hook)")
     if args.expect_drift and not args.drift_probe:
         ap.error("--expect-drift requires --drift-probe")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     mcfg = get_config(args.arch) if args.full else get_smoke_config(args.arch)
     if args.heads is not None:
@@ -316,9 +318,12 @@ def main():
     wall = eng.metrics["wall_s"]
     fr = np.mean([r.stats["footprint_frac"] for r in done])
     bits = np.mean([r.stats["avg_bits"] for r in done])
+    import jax
+    dev = jax.devices()[0]
     print(f"served {len(done)} requests [policy={args.policy}] | {toks} "
-          f"tokens in {wall:.1f}s "
-          f"({toks / wall:.1f} tok/s interp-CPU) | "
+          f"tokens in {wall:.1f}s wall on {dev.platform} "
+          f"({dev.device_kind}, backend={eng.backend}, "
+          f"{toks / wall:.1f} tok/s) | "
           f"mean footprint {fr * 100:.2f}% of FullKV | avg {bits:.2f} bits")
     if args.drift_probe:
         drifts = [r.stats["drift"] for r in done if "drift" in r.stats]

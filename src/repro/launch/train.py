@@ -10,6 +10,7 @@ from __future__ import annotations
 import argparse
 
 import jax
+from jax.sharding import AxisType
 
 from repro.config import MeshConfig, OptimizerConfig, TrainConfig
 from repro.configs import get_config, get_smoke_config
@@ -41,7 +42,8 @@ def main():
         shape = tuple(int(x) for x in args.mesh_shape.split(","))
         names = ("data", "model")[: len(shape)]
         mesh_cfg = MeshConfig(shape=shape, axis_names=names)
-        mesh = jax.make_mesh(shape, names)
+        mesh = jax.make_mesh(shape, names,
+                             axis_types=(AxisType.Auto,) * len(shape))
 
     cfg = TrainConfig(
         model=mcfg, mesh=mesh_cfg,

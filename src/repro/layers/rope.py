@@ -21,23 +21,14 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     """x [..., heads, head_dim]; cos/sin broadcast against x[..., :d//2].
 
     rotate-half: (x1, x2) -> (x1*cos - x2*sin, x2*cos + x1*sin).
-
-    Expressed as reshape[..., 2, d//2] + stack rather than slice + concat of
-    the head_dim halves: when GQA kv_heads < |model| the SPMD partitioner
-    pushes the tensor-parallel sharding into head_dim, and XLA (jax 0.4.37,
-    CPU backend) miscompiles last-axis slice/concat of a sharded head_dim
-    inside a layer scan — even an identity rotate-half (cos=1, sin=0)
-    returns wrong values.  The reshape/stack form is bit-identical math
-    (same (i, i+d/2) pairing) and partitions correctly.
     """
     d = x.shape[-1]
-    xp = x.reshape(*x.shape[:-1], 2, d // 2)
-    x1, x2 = xp[..., 0, :], xp[..., 1, :]
+    x1, x2 = x[..., : d // 2], x[..., d // 2:]
     if cos.ndim == x.ndim - 1:          # add heads axis
         cos = cos[..., None, :]
         sin = sin[..., None, :]
-    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-2)
-    return out.reshape(x.shape).astype(x.dtype)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
 
 
 def rope_single(x: jax.Array, position: jax.Array, theta: float) -> jax.Array:

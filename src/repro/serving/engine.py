@@ -1,7 +1,7 @@
 """ThinKV serving engine: continuous batching + the full paper loop.
 
 The engine owns a SHARED global block pool (``core.ct_cache.GlobalPool``):
-one physical set of quantized planes in paged ``[L, NP, BS, H, ...]``
+one physical set of quantized planes in paged ``[L, NP, H, BS, ...]``
 layout, with per-request per-layer block tables mapping logical CT blocks
 to physical blocks.  Blocks freed by TBE eviction (or request retirement)
 return to the global free list and are reused by other requests.
@@ -139,7 +139,7 @@ end of the prompt).  The sharing/eviction/preemption interplay:
 TENSOR-PARALLEL SHARDING (``mesh=``).  Given a device mesh with a
 ``model`` axis (``launch.mesh.make_serve_mesh("model=N")``), the engine
 shards its HEAVY state over the KV-HEAD axis: pool K/V planes
-(``[L, NP, BS, H, ...]``), TBQ buffers (``[R, L, G, H, D]``), and the
+(``[L, NP, H, BS, ...]``), TBQ buffers (``[R, L, G, H, D]``), and the
 per-layer attention — each shard launches the SAME fused
 ``ct_paged_attention_fused`` kernel over its H/N local heads (still one
 launch per tick per shard).  Everything head-AGNOSTIC stays REPLICATED:
@@ -162,7 +162,10 @@ are wrapped in ``shard_map``:
 Because no FLOATING-POINT reduction ever crosses shards (gathers are
 data movement; the dirty-mask reduction is an integer psum), the sharded
 engine is BIT-IDENTICAL to the 1-device run on both backends — asserted
-end to end by ``tests/test_serving_traces.py``.  Spill/resume under
+end to end on CPU devices by ``tests/test_serving_traces.py``.  On four
+TPU v5e chips it is not yet: the sharded and unsharded programs were
+measured to differ by up to ~0.03 in the logits (``chip_smoke.py
+--chips 4`` fails its token check).  Spill/resume under
 sharding: ``PreemptedState`` GATHERS the shards to host numpy
 (``np.asarray`` of the head-sharded planes) and resume scatters the
 planes back through the freshly claimed table with the head axis
@@ -698,15 +701,14 @@ class ThinKVEngine:
 
     def _wrap_spmd(self, fn, in_specs, out_specs):
         """shard_map a tick/prefill dataflow over the mesh (identity
-        off-mesh).  ``check_rep=False``: replicated outputs are computed
+        off-mesh).  ``check_vma=False``: replicated outputs are computed
         identically on every shard by construction (replicated inputs +
         deterministic ops + explicit gathers), which the static
         replication checker cannot see through collectives."""
         if self.mesh is None:
             return fn
-        from jax.experimental.shard_map import shard_map
-        return shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     # ------------------------------------------------------------------
     # attention helpers shared by tick + prefill
@@ -717,10 +719,10 @@ class ThinKVEngine:
         """Reference path for ONE slot, one layer: gather the request's
         view through its table, dense-dequant, joint softmax with probs.
 
-        q [T, Hq, D]; planes [NP, BS, ...]; state/bits [NS]; table [NB].
+        q [T, Hq, D]; planes [NP, H, BS, ...]; state/bits [NS]; table [NB].
         """
         safe = jnp.maximum(table_l, 0)
-        flat = lambda a: a[safe].reshape(-1, *a.shape[2:])
+        flat = lambda a: CC.page_tokens(a[safe])
         bits = bits_l.astype(jnp.int32)[:, None, None]
         from repro.core import quantization as Q
         kd = Q.dequantize_by_bitcode(flat(kc_l),
@@ -855,7 +857,7 @@ class ThinKVEngine:
                      jnp.swapaxes(caches.slot_state, 0, 1),
                      jnp.swapaxes(caches.slot_bits, 0, 1),
                      jnp.swapaxes(tables, 0, 1),
-                     CC.stacked_buffers(buf_k), CC.stacked_buffers(buf_v)))
+                     jnp.swapaxes(buf_k, 0, 1), jnp.swapaxes(buf_v, 0, 1)))
                 sparsity = jnp.mean(spars_all[lstar_arr], axis=0)  # [R]
 
             # shard-local attention rejoins the replicated stream here:
@@ -1101,7 +1103,8 @@ class ThinKVEngine:
 
         ``tok_valid=None`` means the chunk is FULL (the large-chunk path):
         the intra-chunk partition then runs the compiled ``flash_prefill``
-        kernel (the chunk length is a 128-multiple).  With a mask (the
+        kernel (the chunk length must be a 128-multiple, or
+        ``prefill_attention_stats`` raises).  With a mask (the
         g-sized tail path, chunk <= 16 tokens — below the kernel's 128
         tile) it runs the reference oracle.
         """
@@ -1125,7 +1128,7 @@ class ThinKVEngine:
         o_c, m_c, l_c = K.prefill_attention_stats(
             q.astype(jnp.float32), k_chunk.astype(jnp.float32),
             v_chunk.astype(jnp.float32), causal=True, kv_valid=tok_valid,
-            force=self._force if tok_valid is None else None)
+            force=self._force)
         return KR.merge_flash_ref(o_p, m_p, l_p, o_c, m_c,
                                   l_c).astype(q.dtype)
 
@@ -2249,7 +2252,15 @@ class ThinKVEngine:
 
     # ------------------------------------------------------------------
     def slot_stats(self, i: int) -> Dict:
+        """Footprint and cache-evolution counts of slot ``i``'s request:
+        ``committed_tokens`` went through group commits, ``valid_tokens``
+        [L] of them are still attended (the rest were evicted), and
+        ``refreshes`` counts closed segments (only a thought refresh
+        advances ``cur_seg``; it saturates at ``max_segments - 1``)."""
         one = jax.tree.map(lambda x: x[i], self.caches)
         from repro.core.thinkv import compression_ratio
         comp = compression_ratio(self.tk, self.dims, one, one.num_tokens)
-        return {k: np.asarray(v).tolist() for k, v in comp.items()}
+        out = {k: np.asarray(v).tolist() for k, v in comp.items()}
+        out["committed_tokens"] = int(one.num_tokens - one.buf_len)
+        out["refreshes"] = int(one.cur_seg)
+        return out

@@ -219,17 +219,20 @@ def _pool_attention_kernel(q, k_codes, v_codes, k_scales, v_scales,
     through an identity table (serve_step batches are per-request pools by
     construction) AND folds the fp-buffer attention into the kernel's final
     grid step — the (pool, buffer) flash merge happens in VMEM, no (m, l)
-    stats plumbing back to XLA."""
+    stats plumbing back to XLA.  The batch's token-major ``[NB, BS, H,
+    ...]`` pages are transposed to the kernel's ``[NB, H, BS, ...]``."""
     from repro.kernels import ops as K
     nb, bs, h = k_codes.shape[0], k_codes.shape[1], k_codes.shape[2]
     hq, hd = q.shape
     gq = hq // h
     qh = q.reshape(1, 1, h, gq, hd).astype(jnp.float32)
     table = jnp.arange(nb, dtype=jnp.int32)[None, None]       # [R=1, L=1]
+    pages = lambda a: jnp.swapaxes(a, 1, 2)[None]
+    heads = lambda b: jnp.swapaxes(b, 0, 1)[None, None]
     out = K.paged_decode_attention_fused(
-        qh, k_codes[None], v_codes[None], k_scales[None], v_scales[None],
+        qh, pages(k_codes), pages(v_codes), pages(k_scales), pages(v_scales),
         slot_state.reshape(1, 1, nb, bs), slot_bits.reshape(1, 1, nb, bs),
-        table, buf_k[None, None], buf_v[None, None],
+        table, heads(buf_k), heads(buf_v),
         buf_len.reshape(1).astype(jnp.int32), force=force)
     return out.reshape(hq, hd).astype(q.dtype)
 

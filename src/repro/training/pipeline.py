@@ -17,7 +17,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -75,4 +75,4 @@ def pipeline_apply(stage_fn: Callable, stage_params, h0: jax.Array,
         local, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False)(stage_params, h0)
+        check_vma=False)(stage_params, h0)

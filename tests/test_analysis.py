@@ -162,10 +162,10 @@ def test_collective_census_and_float_psum_violation():
     """The census records every collective with dtype + axis; the serve
     whitelist passes the tiled all_gather and the integer psum, and
     rejects a float psum naming primitive, dtype, and shard_map path."""
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import Mesh, PartitionSpec as P
+    from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
-    mesh = Mesh(np.array(jax.devices()[:1]), ("model",))
+    mesh = Mesh(np.array(jax.devices()[:1]), ("model",),
+                axis_types=(AxisType.Auto,))
 
     def body(x, m):
         g = jax.lax.all_gather(x, "model", axis=0, tiled=True)
@@ -173,8 +173,8 @@ def test_collective_census_and_float_psum_violation():
         bad = jax.lax.psum(x, "model")                   # float: forbidden
         return g + bad, dirty
 
-    f = shard_map(body, mesh=mesh, in_specs=(P("model"), P()),
-                  out_specs=(P(), P()), check_rep=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=(P("model"), P()),
+                      out_specs=(P(), P()), check_vma=False)
     census = census_of(jax.make_jaxpr(f)(
         jnp.ones(4, jnp.float32), jnp.ones((), jnp.int32)))
     got = {(c.name, c.dtype) for c in census.collectives}

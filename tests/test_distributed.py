@@ -17,6 +17,7 @@ else:
     import dataclasses
 
     import jax
+    from jax.sharding import AxisType
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -33,7 +34,8 @@ else:
     from repro.config import OptimizerConfig
 
     def _mesh(shape, names):
-        return jax.make_mesh(shape, names)
+        return jax.make_mesh(shape, names,
+                             axis_types=(AxisType.Auto,) * len(shape))
 
     def test_eight_devices():
         assert jax.device_count() == 8
@@ -196,60 +198,3 @@ else:
                                    chunks=2)
         assert y.shape == (n, d)
         assert bool(jnp.isfinite(y).all())
-
-    @pytest.mark.xfail(
-        strict=True,
-        reason="XLA CPU SPMD miscompiles last-axis slice/concat of a "
-               "sharded head_dim inside a layer scan (jax 0.4.37; see "
-               "ROADMAP open items) — apply_rope works around it with a "
-               "bit-identical reshape/stack form.  STRICT: when a JAX "
-               "bump fixes this, the XPASS fails loudly and tells us the "
-               "workaround (and this canary) can be dropped.")
-    def test_xla_spmd_rope_slice_concat_canary():
-        """The ORIGINAL rotate-half formulation (slice + concat of the
-        head_dim halves), swapped in for the workaround, must make the
-        (2,4)-mesh yi-6b train step match the single-device loss — today
-        it does NOT (the historical 0.9% loss mismatch)."""
-        import repro.layers.attention as attn_mod
-        import repro.layers.rope as rope_mod
-
-        def rope_slice_concat(x, cos, sin):
-            d = x.shape[-1]
-            x1, x2 = x[..., : d // 2], x[..., d // 2:]
-            if cos.ndim == x.ndim - 1:
-                cos = cos[..., None, :]
-                sin = sin[..., None, :]
-            return jnp.concatenate(
-                [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                axis=-1).astype(x.dtype)
-
-        orig = rope_mod.apply_rope
-        attn_mod.apply_rope = rope_mod.apply_rope = rope_slice_concat
-        try:
-            rng = np.random.default_rng(0)
-            cfg = get_smoke_config("yi-6b")
-            model = build_model(cfg)
-            params = model.init_params(0)
-            opt = adamw_init(params)
-            batch = {
-                "tokens": jnp.asarray(
-                    rng.integers(0, cfg.vocab_size, (8, 16)), jnp.int32),
-                "targets": jnp.asarray(
-                    rng.integers(0, cfg.vocab_size, (8, 16)), jnp.int32)}
-            step = make_train_step(model.loss, cfg, OptimizerConfig(),
-                                   remat=True)
-            _, _, m1 = jax.jit(step)(params, opt, batch)
-            mesh = _mesh((2, 4), ("data", "model"))
-            psh = SH.param_shardings(params, mesh)
-            bsh = SH.to_shardings(SH.train_batch_specs(batch, mesh), mesh)
-            params_s = jax.device_put(params, psh)
-            opt_s = type(opt)(step=opt.step,
-                              m=jax.device_put(opt.m, psh),
-                              v=jax.device_put(opt.v, psh))
-            batch_s = jax.device_put(batch, bsh)
-            with mesh:
-                _, _, m2 = jax.jit(step)(params_s, opt_s, batch_s)
-        finally:
-            attn_mod.apply_rope = rope_mod.apply_rope = orig
-        np.testing.assert_allclose(float(m1["loss"]), float(m2["loss"]),
-                                   rtol=2e-5)

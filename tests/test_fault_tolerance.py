@@ -4,6 +4,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 import numpy as np
 import pytest
 
@@ -102,7 +103,7 @@ def test_loss_decreases(tmp_path):
 def test_elastic_restore_changes_sharding(tmp_path):
     """Save unsharded, restore with explicit shardings (mesh of 1) — the
     cross-topology protocol (value equality + requested sharding)."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     from jax.sharding import NamedSharding, PartitionSpec as P
     tree = {"w": jnp.arange(16, dtype=jnp.float32).reshape(4, 4)}
     CKPT.save(tmp_path, 7, tree)

@@ -226,8 +226,8 @@ def _fused_args(rng, layers, kv_heads=2, head_dim=64, precision=(2, 4, 8),
     block_free = ~(state == 1).any(axis=2) & ~(state == 2).any(axis=2)
     for l in range(L):
         tables[-1, l][block_free[l]] = -1
-    buf_k = rng.standard_normal((L, requests, G, dims.H, dims.D))
-    buf_v = rng.standard_normal((L, requests, G, dims.H, dims.D))
+    buf_k = rng.standard_normal((L, requests, dims.H, G, dims.D))
+    buf_v = rng.standard_normal((L, requests, dims.H, G, dims.D))
     buf_len = np.linspace(0, G, requests).astype(np.int32)
     return dims, dict(
         k_codes=view.k_codes, v_codes=view.v_codes,
@@ -313,6 +313,21 @@ def test_large_chunk_prefill_kernel_vs_chunked_ref(rng, chunk):
                                rtol=3e-5, atol=3e-5)
     np.testing.assert_allclose(np.asarray(l_k), np.asarray(l_r),
                                rtol=3e-5, atol=3e-5)
+
+
+def test_prefill_kernel_path_refuses_partial_tile(rng):
+    """An unpadded chunk on the kernel path that is not a 128-multiple
+    raises instead of dropping to the oracle; a padded chunk (kv_valid
+    given) takes the oracle by design."""
+    q = jnp.asarray(rng.standard_normal((64, 4, 32)), jnp.float32)
+    kv = jnp.asarray(rng.standard_normal((64, 2, 32)), jnp.float32)
+    with pytest.raises(ValueError, match="128-multiple"):
+        ops.prefill_attention_stats(q, kv, kv, force="pallas")
+    valid = jnp.arange(64) < 40
+    o, _, _ = ops.prefill_attention_stats(q, kv, kv, kv_valid=valid,
+                                          force="pallas")
+    o_r, _, _ = R.flash_prefill_stats_ref(q, kv, kv, kv_valid=valid)
+    np.testing.assert_array_equal(np.asarray(o), np.asarray(o_r))
 
 
 def test_full_thinkv_attention_kernel_path(rng):

@@ -34,6 +34,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 import numpy as np
 import pytest
 
@@ -84,18 +85,17 @@ def _make_step(dims, sharded: bool):
 
     if not sharded:
         return jax.jit(step)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.distributed import sharding as SH
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = jax.make_mesh((8,), ("model",), axis_types=(AxisType.Auto,))
     pool_s = SH.serve_pool_specs(CC.init_global_pool(dims, 1))
     cache_s = SH.serve_cache_specs(CC.init_cache(dims), batched=False)
     rep = P()
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         step, mesh=mesh,
         in_specs=(pool_s, rep, cache_s, rep, rep, rep),
         out_specs=(pool_s, rep, cache_s, rep, rep),
-        check_rep=False))
+        check_vma=False))
 
 
 def _assert_shards_agree(arr, what):
@@ -125,7 +125,8 @@ class _Harness:
         self._step = _make_step(dims, sharded)
         if sharded:
             from repro.distributed import sharding as SH
-            mesh = jax.make_mesh((8,), ("model",))
+            mesh = jax.make_mesh((8,), ("model",),
+                                 axis_types=(AxisType.Auto,))
             self.pool = jax.device_put(
                 self.pool,
                 SH.to_shardings(SH.serve_pool_specs(self.pool), mesh))

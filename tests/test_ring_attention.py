@@ -13,6 +13,7 @@ if not has_mesh_devices():
         run_in_mesh_subprocess(__file__, timeout=1200)
 else:
     import jax
+    from jax.sharding import AxisType
     import jax.numpy as jnp
     import numpy as np
 
@@ -20,7 +21,8 @@ else:
     from repro.layers.attention import _dense_attention
 
     def _run(mesh_shape, names, b, s, hq, hkv, d, seed=0):
-        mesh = jax.make_mesh(mesh_shape, names)
+        mesh = jax.make_mesh(mesh_shape, names,
+                             axis_types=(AxisType.Auto,) * len(names))
         rng = np.random.default_rng(seed)
         q = jnp.asarray(rng.standard_normal((b, s, hq, d)), jnp.float32)
         k = jnp.asarray(rng.standard_normal((b, s, hkv, d)), jnp.float32)

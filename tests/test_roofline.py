@@ -1,6 +1,7 @@
 """Roofline machinery: HLO cost model accuracy + term arithmetic."""
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 import numpy as np
 import pytest
 
@@ -55,7 +56,7 @@ def test_nested_scan():
 def test_collective_bytes_on_sharded_program():
     if jax.device_count() < 1:
         pytest.skip("no devices")
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",), axis_types=(AxisType.Auto,))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     def f(x):
