@@ -56,6 +56,7 @@ from repro.config import ThinKVConfig, ThoughtType
 from repro.core import quantization as Q
 from repro.core.policy import get_policy
 from repro.core.thoughts import classify
+from repro.serving import tracing as TR
 
 SCALE_DTYPE = jnp.bfloat16      # e4m3-rounded values (see module docstring)
 
@@ -961,23 +962,28 @@ def engine_advance(cfg: ThinKVConfig, dims: CacheDims, pool: GlobalPool,
 
         def maintain(args):
             pool, table, cache, _, _ = args
-            view0 = gather_view(pool.view, table)
-            cache, view = commit_and_evict_if_full(cfg, dims, cache, view0,
-                                                   axis_name=axis_name,
-                                                   policy=policy)
-            cache = jax.lax.cond(
-                at_refresh,
-                lambda c: refresh(cfg, dims, c, view, sparsity,
-                                  axis_name=axis_name, policy=policy),
-                lambda c: c, cache)
-            if track_cow:
-                # a slot dirty in ANY shard's heads must COW on EVERY
-                # shard (the table/refcount updates are replicated)
-                dirty = _any_shard(changed_slots(view0, view), axis_name)
-            else:
-                dirty = None
-            pool, table, cache, failed, cow = sync_block_tables(
-                dims, pool, table, cache, view, dirty_slots=dirty)
+            with jax.named_scope(TR.GATHER_VIEW):
+                view0 = gather_view(pool.view, table)
+            with jax.named_scope(TR.COMMIT_EVICT):
+                cache, view = commit_and_evict_if_full(
+                    cfg, dims, cache, view0, axis_name=axis_name,
+                    policy=policy)
+            with jax.named_scope(TR.REFRESH):
+                cache = jax.lax.cond(
+                    at_refresh,
+                    lambda c: refresh(cfg, dims, c, view, sparsity,
+                                      axis_name=axis_name, policy=policy),
+                    lambda c: c, cache)
+            with jax.named_scope(TR.SYNC_TABLES):
+                if track_cow:
+                    # a slot dirty in ANY shard's heads must COW on EVERY
+                    # shard (the table/refcount updates are replicated)
+                    dirty = _any_shard(changed_slots(view0, view),
+                                       axis_name)
+                else:
+                    dirty = None
+                pool, table, cache, failed, cow = sync_block_tables(
+                    dims, pool, table, cache, view, dirty_slots=dirty)
             return (pool, table, cache, jnp.any(failed),
                     jnp.sum(cow.astype(jnp.int32)))
 

@@ -218,6 +218,7 @@ from repro.layers.moe import moe_apply
 from repro.layers.norms import rmsnorm
 from repro.layers.rope import apply_rope, rope_freqs
 from repro.serving import sampling as SMP
+from repro.serving import tracing as TR
 from repro.serving.scheduler import Request, Scheduler
 
 NEG_INF = -1e30
@@ -365,11 +366,13 @@ class ResultTokens:
     def block(self) -> "ResultTokens":
         """Wait for the D2H copies; host views cached idempotently."""
         if self._host is None:
-            cow = np.asarray(self._cow_faults).astype(np.int64)
-            self._host = (np.asarray(self._tokens),
-                          np.asarray(self._logits),
-                          bool(np.any(np.asarray(self._alloc_fail))),
-                          int(cow.sum()), cow)
+            with TR.span(TR.FETCH_TOKENS):
+                tokens = np.asarray(self._tokens)
+                fail = bool(np.any(np.asarray(self._alloc_fail)))
+                cow = np.asarray(self._cow_faults).astype(np.int64)
+            with TR.span(TR.FETCH_LOGITS):
+                logits = np.asarray(self._logits)
+            self._host = (tokens, logits, fail, int(cow.sum()), cow)
         return self
 
     @property
@@ -439,12 +442,15 @@ class MultiResultTokens:
     def block(self) -> "MultiResultTokens":
         """Wait for the D2H copies; host views cached idempotently."""
         if self._host is None:
-            self._host = (np.asarray(self._tokens),
-                          np.asarray(self._valid),
-                          np.asarray(self._logits),
-                          bool(np.any(np.asarray(self._alloc_fail))),
-                          np.asarray(self._cow_faults).astype(np.int64),
-                          int(np.asarray(self._trips)))
+            with TR.span(TR.FETCH_TOKENS):
+                tokens = np.asarray(self._tokens)
+                valid = np.asarray(self._valid)
+                fail = bool(np.any(np.asarray(self._alloc_fail)))
+                cow = np.asarray(self._cow_faults).astype(np.int64)
+                trips = int(np.asarray(self._trips))
+            with TR.span(TR.FETCH_LOGITS):
+                logits = np.asarray(self._logits)
+            self._host = (tokens, valid, logits, fail, cow, trips)
         return self
 
     @property
@@ -624,7 +630,8 @@ class ThinKVEngine:
                                           "early_exit_headroom": 0,
                                           "cancellations": 0,
                                           "drift_probes": 0,
-                                          "drift_max_abs": 0.0}
+                                          "drift_max_abs": 0.0,
+                                          "host_syncs": 0}
         from repro.serving.prefix_cache import PrefixCache
         self.prefix_cache = PrefixCache(
             self.dims, capacity=prefix_cache_capacity) \
@@ -752,8 +759,12 @@ class ThinKVEngine:
         Hq_loc = cfg.num_heads // self._nshard
 
         def tick_core(params, pool, tables, caches, tokens, active):
-            h = jax.vmap(lambda t: E.embed(params["embed"], t[None],
-                                           cfg)[0])(tokens)      # [R, Dm]
+            # every phase runs under a named scope (``serving.tracing``),
+            # so the device trace can attribute its operations
+            with TR.scope(TR.TICK_CORE):
+                return phases(params, pool, tables, caches, tokens, active)
+
+        def phases(params, pool, tables, caches, tokens, active):
             pos = caches.num_tokens                              # [R]
             buf_len = caches.buf_len                             # [R]
             # slots whose refresh fires in THIS tick's engine_advance
@@ -786,9 +797,12 @@ class ThinKVEngine:
                     m = mlp(lp["mlp"], x2, cfg.act, cfg.mlp_gated)
                 return (h + m, buf_k, buf_v), q
 
-            (h, buf_k, buf_v), qs = jax.lax.scan(
-                trunk, (h, caches.buf_k, caches.buf_v),
-                (jnp.arange(cfg.num_layers), params["layers"]))
+            with TR.scope(TR.TRUNK):
+                h = jax.vmap(lambda t: E.embed(params["embed"], t[None],
+                                               cfg)[0])(tokens)  # [R, Dm]
+                (h, buf_k, buf_v), qs = jax.lax.scan(
+                    trunk, (h, caches.buf_k, caches.buf_v),
+                    (jnp.arange(cfg.num_layers), params["layers"]))
             caches = caches.replace(buf_k=buf_k, buf_v=buf_v)
             n_buf = buf_len + 1                                  # [R]
             # queries of this shard's kv heads ([L, R, Hq/N, hd]; the Hq
@@ -822,27 +836,29 @@ class ThinKVEngine:
 
             # ---- pass 2: attention, ONCE, over the stacked queries ----
             if backend == "kernel":
-                qh = qs_loc.reshape(cfg.num_layers, R, H_loc, gq,
-                                    cfg.head_dim).astype(jnp.float32)
-                o_all = K.paged_decode_attention_fused(
-                    qh, pool.view.k_codes, pool.view.v_codes,
-                    pool.view.k_scales, pool.view.v_scales,
-                    CC.stacked_slot_plane(dims, caches.slot_state),
-                    CC.stacked_slot_plane(dims, caches.slot_bits),
-                    tables, CC.stacked_buffers(buf_k),
-                    CC.stacked_buffers(buf_v), n_buf, force=self._force)
-                o_all = o_all.reshape(cfg.num_layers, R, Hq_loc,
-                                      cfg.head_dim).astype(qs.dtype)
+                with TR.scope(TR.ATTENTION):
+                    qh = qs_loc.reshape(cfg.num_layers, R, H_loc, gq,
+                                        cfg.head_dim).astype(jnp.float32)
+                    o_all = K.paged_decode_attention_fused(
+                        qh, pool.view.k_codes, pool.view.v_codes,
+                        pool.view.k_scales, pool.view.v_scales,
+                        CC.stacked_slot_plane(dims, caches.slot_state),
+                        CC.stacked_slot_plane(dims, caches.slot_bits),
+                        tables, CC.stacked_buffers(buf_k),
+                        CC.stacked_buffers(buf_v), n_buf, force=self._force)
+                    o_all = o_all.reshape(cfg.num_layers, R, Hq_loc,
+                                          cfg.head_dim).astype(qs.dtype)
                 # sparsity is only CONSUMED at tau refresh boundaries — run
                 # the dense probs pass for the calibrated layers only on
                 # ticks where some slot is about to refresh, keeping the
                 # kernel path free of per-token dense-dequant traffic
-                spars_calib = jax.lax.cond(
-                    jnp.any(refresh_due),
-                    lambda: jnp.stack([dense_layer_all_slots(l)[1]
-                                       for l in lstar]),
-                    lambda: jnp.zeros((len(lstar), R), jnp.float32))
-                sparsity = jnp.mean(spars_calib, axis=0)         # [R]
+                with TR.scope(TR.PROBE):
+                    spars_calib = jax.lax.cond(
+                        jnp.any(refresh_due),
+                        lambda: jnp.stack([dense_layer_all_slots(l)[1]
+                                           for l in lstar]),
+                        lambda: jnp.zeros((len(lstar), R), jnp.float32))
+                    sparsity = jnp.mean(spars_calib, axis=0)     # [R]
             else:
                 def attend(_, inp):
                     (q_l, kc_l, vc_l, ks_l, vs_l, st_l, bt_l, tb_l, bk_l,
@@ -850,27 +866,30 @@ class ThinKVEngine:
                     return 0, dense_one_layer(kc_l, vc_l, ks_l, vs_l, q_l,
                                               st_l, bt_l, tb_l, bk_l, bv_l)
 
-                _, (o_all, spars_all) = jax.lax.scan(
-                    attend, 0,
-                    (qs_loc, pool.view.k_codes, pool.view.v_codes,
-                     pool.view.k_scales, pool.view.v_scales,
-                     jnp.swapaxes(caches.slot_state, 0, 1),
-                     jnp.swapaxes(caches.slot_bits, 0, 1),
-                     jnp.swapaxes(tables, 0, 1),
-                     jnp.swapaxes(buf_k, 0, 1), jnp.swapaxes(buf_v, 0, 1)))
-                sparsity = jnp.mean(spars_all[lstar_arr], axis=0)  # [R]
-
-            # shard-local attention rejoins the replicated stream here:
-            # all-gather the head axis, then the output projection +
-            # residual run replicated (bit-identical to 1-device)
-            o_all = self._gather_heads(o_all, 2)
+                with TR.scope(TR.ATTENTION):
+                    _, (o_all, spars_all) = jax.lax.scan(
+                        attend, 0,
+                        (qs_loc, pool.view.k_codes, pool.view.v_codes,
+                         pool.view.k_scales, pool.view.v_scales,
+                         jnp.swapaxes(caches.slot_state, 0, 1),
+                         jnp.swapaxes(caches.slot_bits, 0, 1),
+                         jnp.swapaxes(tables, 0, 1),
+                         jnp.swapaxes(buf_k, 0, 1),
+                         jnp.swapaxes(buf_v, 0, 1)))
+                    sparsity = jnp.mean(spars_all[lstar_arr], axis=0)
 
             # ---- pass 3: attention output residuals ----
             def residual(hc, inp):
                 lp, o_l = inp
                 return hc + A.out_proj(lp["attn"], o_l), None
 
-            h, _ = jax.lax.scan(residual, h, (params["layers"], o_all))
+            with TR.scope(TR.RESIDUAL):
+                # shard-local attention rejoins the replicated stream
+                # here: all-gather the head axis, then the output
+                # projection + residual run replicated (bit-identical to
+                # 1-device)
+                o_all = self._gather_heads(o_all, 2)
+                h, _ = jax.lax.scan(residual, h, (params["layers"], o_all))
 
             # cache maintenance against the shared pool: sequential over
             # slots (disjoint physical blocks; allocation is serialized).
@@ -884,12 +903,15 @@ class ThinKVEngine:
                     axis_name=ax, policy=self.policy)
                 return pool, (table_r, cache_r, fail_r, cow_r)
 
-            pool, (tables_out, caches, alloc_fail, cow_faults) = \
-                jax.lax.scan(adv, pool, (caches, tables, sparsity, active))
+            with TR.scope(TR.ADVANCE):
+                pool, (tables_out, caches, alloc_fail, cow_faults) = \
+                    jax.lax.scan(adv, pool,
+                                 (caches, tables, sparsity, active))
 
-            h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
-            logits = softcap(E.unembed(params["embed"], h, cfg),
-                             cfg.logit_softcap)                  # [R, V]
+            with TR.scope(TR.UNEMBED):
+                h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
+                logits = softcap(E.unembed(params["embed"], h, cfg),
+                                 cfg.logit_softcap)              # [R, V]
             return (pool, tables_out, caches, sparsity, logits,
                     alloc_fail, cow_faults)
 
@@ -908,7 +930,9 @@ class ThinKVEngine:
             (pool, tables_out, caches, sparsity, logits, alloc_fail,
              cow_faults) = core(params, pool, tables, caches, tokens,
                                 active)
-            nxt, slot_rngs = _sample_slots(slot_rngs, logits, temp, top_p)
+            with TR.scope(TR.SAMPLE):
+                nxt, slot_rngs = _sample_slots(slot_rngs, logits, temp,
+                                               top_p)
             return (nxt, pool, tables_out, caches, sparsity, logits,
                     alloc_fail, cow_faults, slot_rngs)
 
@@ -959,8 +983,9 @@ class ThinKVEngine:
                  produced, toks, valid, logits_buf, fail, _stop, cow) = c
                 (pool, tables, caches, _, logits, fail_t, cow_t) = core(
                     params, pool, tables, caches, tokens, active)
-                nxt, slot_rngs = _sample_slots(slot_rngs, logits, temp,
-                                               top_p)
+                with TR.scope(TR.SAMPLE):
+                    nxt, slot_rngs = _sample_slots(slot_rngs, logits, temp,
+                                                   top_p)
                 toks = toks.at[t].set(nxt)
                 valid = valid.at[t].set(active)
                 logits_buf = logits_buf.at[t].set(logits)
@@ -1304,8 +1329,8 @@ class ThinKVEngine:
         pad = -(-max(n, 1) // DRIFT_PAD) * DRIFT_PAD
         buf = np.zeros((1, pad), np.int32)
         buf[0, :n] = toks[:n]
-        ref = np.asarray(self._drift_probe_jit(self.params,
-                                               jnp.asarray(buf)))[0]
+        ref = self._fetch(self._drift_probe_jit(self.params,
+                                                jnp.asarray(buf)))[0]
         steps = min(len(output), len(recorded))
         max_abs = mean_abs = 0.0
         top1 = 0
@@ -1438,8 +1463,18 @@ class ThinKVEngine:
     # oversubscribed-pool admission + preemption (host side)
     # ------------------------------------------------------------------
 
+    def _fetch(self, x):
+        """Every blocking device-to-host read the engine's host paths
+        make, outside the tick's result fetch, goes through here:
+        ``jax.device_get`` of an array or a pytree of arrays, counted
+        once in ``metrics["host_syncs"]`` and spanned as
+        ``engine/sync``."""
+        self.metrics["host_syncs"] += 1
+        with TR.span(TR.SYNC):
+            return jax.device_get(x)
+
     def _free_per_layer(self) -> np.ndarray:
-        return np.asarray(jnp.sum(self.pool.free, axis=1)).astype(np.int64)
+        return self._fetch(jnp.sum(self.pool.free, axis=1)).astype(np.int64)
 
     def _split_table(self, table_np: np.ndarray, rc: np.ndarray = None):
         """``[L, NB]`` (private, shared) masks of a raw block table
@@ -1453,7 +1488,7 @@ class ThinKVEngine:
         single definition keeps preemption spilling, headroom estimates,
         and victim scoring consistent."""
         if rc is None:
-            rc = np.asarray(self.pool.refcount)              # [L, NP]
+            rc = self._fetch(self.pool.refcount)             # [L, NP]
         mapped = table_np >= 0
         rc_at = np.take_along_axis(rc, np.clip(table_np, 0, None), axis=1)
         private = mapped & (rc_at == 1)
@@ -1461,7 +1496,7 @@ class ThinKVEngine:
 
     def _split_held(self, i: int, rc: np.ndarray = None):
         """Per-layer (private, shared) mapped-block counts of slot ``i``."""
-        private, shared = self._split_table(np.asarray(self.tables[i]), rc)
+        private, shared = self._split_table(self._fetch(self.tables[i]), rc)
         return (private.sum(axis=1).astype(np.int64),
                 shared.sum(axis=1).astype(np.int64))
 
@@ -1531,7 +1566,7 @@ class ThinKVEngine:
             return False
         # ONE refcount transfer per call; evictions are mirrored on the
         # host copies (only this loop mutates the pool while it runs)
-        rc = np.asarray(self.pool.refcount).copy()           # [L, NP]
+        rc = self._fetch(self.pool.refcount).copy()          # [L, NP]
         cache_refs = np.zeros_like(rc)
         for t in self.prefix_cache.cached_tables():
             for l in range(self.dims.L):
@@ -1668,17 +1703,20 @@ class ThinKVEngine:
         req = slot.request
         assert self._slot_ntok[i] > 0, \
             "preempting a slot that never started (nothing to spill)"
-        table_np = np.asarray(self.tables[i])                # [L, NB]
+        table_np = self._fetch(self.tables[i])               # [L, NB]
         private, shared = self._split_table(table_np)
         view, _ = CC.extract_request(self.dims, self.pool, self.tables[i])
+        view, cache, rng = self._fetch(
+            (tuple(view), jax.tree.map(lambda x: x[i], self.caches),
+             self._slot_rng[i]))
         self._spilled[req.arrival] = PreemptedState(
-            view=tuple(np.asarray(p) for p in view),
+            view=view,
             mapped=private,
-            cache=jax.tree.map(lambda x: np.asarray(x[i]), self.caches),
+            cache=cache,
             tokens_out=slot.tokens_out,
             next_token=int(self._feed[i]),
             shared_table=np.where(shared, table_np, -1).astype(np.int32),
-            rng=np.asarray(self._slot_rng[i]))
+            rng=rng)
         # decref only the private blocks; the shared references ride
         # along in the spill (audited via audit_pool)
         self._release_slot(
@@ -1727,7 +1765,7 @@ class ThinKVEngine:
             return
         # ONE refcount transfer serves every per-slot demand estimate
         # (and none at all while nothing can be shared)
-        rc = np.asarray(self.pool.refcount) \
+        rc = self._fetch(self.pool.refcount) \
             if self._sharing_possible() else None
         demand = {i: self._cc + self._cow_demand(i, rc) for i in committing}
         need = sum(demand.values())
@@ -1761,7 +1799,7 @@ class ThinKVEngine:
         one tick's commits fit."""
         if cap <= 1:
             return 1
-        rc = np.asarray(self.pool.refcount) \
+        rc = self._fetch(self.pool.refcount) \
             if self._sharing_possible() else None
         free = (rc == 0).sum(axis=1).astype(np.int64) if rc is not None \
             else self._free_per_layer()
@@ -1783,7 +1821,7 @@ class ThinKVEngine:
         entries first, then preempting OTHER running slots.  Raises only
         when nothing is preemptible and the pool still cannot back the
         commit (a pool too small for a single request)."""
-        rc = np.asarray(self.pool.refcount) \
+        rc = self._fetch(self.pool.refcount) \
             if self._sharing_possible() else None
         n_blocks = n_blocks + self._cow_demand(idx, rc)
         free = (rc == 0).sum(axis=1).astype(np.int64) if rc is not None \
@@ -1900,8 +1938,8 @@ class ThinKVEngine:
             # Shared blocks add one potential COW claim each (the copy is
             # NEW pool demand: the source stays claimed by other holders)
             self.tables = self.tables.at[i].set(table_i)
-            t_np = np.asarray(table_i)
-            rc = np.asarray(self.pool.refcount)   # ONE transfer per chunk
+            t_np = self._fetch(table_i)
+            rc = self._fetch(self.pool.refcount)  # ONE transfer per chunk
             shared = self._split_table(t_np, rc)[1]
             mapped = (t_np >= 0).sum(axis=1)                  # [L]
             need = np.minimum(big_claims, dims.NB - mapped) + \
@@ -1918,7 +1956,7 @@ class ThinKVEngine:
                 jnp.asarray(chunk))
             fails.append(fail)
             self.metrics["prefill_big_chunks"] += 1
-            self.metrics["cow_faults"] += int(np.asarray(n_cow))
+            self.metrics["cow_faults"] += int(self._fetch(n_cow))
             s0 += BC
             register(s0, logits)
         for s in range(s0, len(prompt), C):
@@ -1938,7 +1976,7 @@ class ThinKVEngine:
                 jnp.asarray(padded), jnp.int32(n_valid))
             fails.append(fail)
             self.metrics["prefill_chunks"] += 1
-            self.metrics["cow_faults"] += int(np.asarray(n_cow))
+            self.metrics["cow_faults"] += int(self._fetch(n_cow))
             register(s + n_valid, logits)
         self.metrics["prefill_tokens"] += len(prompt) - (hit.length
                                                          if hit else 0)
@@ -1946,14 +1984,15 @@ class ThinKVEngine:
         self.tables = self.tables.at[i].set(table_i)
         self.caches = jax.tree.map(
             lambda all_, one: all_.at[i].set(one), self.caches, cache_i)
-        if any(bool(f) for f in fails):
+        if any(bool(f) for f in self._fetch(fails)):
             raise AssertionError(
                 "prefill commit allocation failed despite headroom checks "
                 "(pool accounting bug — data would have been dropped)")
+        logits = self._fetch(logits)
         if self.record_logits:
             self.trace.append({"kind": "prefill", "slot": i,
-                               "logits": np.asarray(logits)})
-        return np.asarray(logits)
+                               "logits": logits})
+        return logits
 
     # ------------------------------------------------------------------
     # the device-facing API seam: prefill / insert / generate /
@@ -1980,14 +2019,17 @@ class ThinKVEngine:
         The legacy ``rng`` argument is threaded through untouched for
         caller-loop compatibility; ``arrival=None`` falls back to the
         slot index (single-shot harnesses without a scheduler)."""
-        logits = self._prefill(slot_idx, np.asarray(prompt))
-        key = SMP.request_stream_key(
-            self.cfg.seed, slot_idx if arrival is None else arrival)
-        tok, key = SMP.stream_sample(key, jnp.asarray(logits),
-                                     self.cfg.temperature, self.cfg.top_p)
-        self._slot_rng = self._slot_rng.at[slot_idx].set(key)
-        return Prefix(length=len(prompt), first_token=int(tok),
-                      logits=logits, slot=slot_idx), rng
+        with TR.span(TR.PREFILL, arrival=arrival):
+            logits = self._prefill(slot_idx, np.asarray(prompt))
+            key = SMP.request_stream_key(
+                self.cfg.seed, slot_idx if arrival is None else arrival)
+            tok, key = SMP.stream_sample(key, jnp.asarray(logits),
+                                         self.cfg.temperature,
+                                         self.cfg.top_p)
+            self._slot_rng = self._slot_rng.at[slot_idx].set(key)
+            return Prefix(length=len(prompt),
+                          first_token=int(self._fetch(tok)),
+                          logits=logits, slot=slot_idx), rng
 
     def detach_prefix(self, prefix: Prefix) -> Prefix:
         """Convert a RESIDENT prefix into the PORTABLE transfer form:
@@ -2001,15 +2043,18 @@ class ThinKVEngine:
         assert prefix.state is None and prefix.slot >= 0, \
             "detach_prefix needs a RESIDENT prefix"
         i = prefix.slot
-        table_np = np.asarray(self.tables[i])
+        table_np = self._fetch(self.tables[i])
         view, _ = CC.extract_request(self.dims, self.pool, self.tables[i])
+        view, cache, rng = self._fetch(
+            (tuple(view), jax.tree.map(lambda x: x[i], self.caches),
+             self._slot_rng[i]))
         prefix.state = PreemptedState(
-            view=tuple(np.asarray(p) for p in view),
+            view=view,
             mapped=table_np >= 0,
-            cache=jax.tree.map(lambda x: np.asarray(x[i]), self.caches),
+            cache=cache,
             tokens_out=0,
             next_token=prefix.first_token,
-            rng=np.asarray(self._slot_rng[i]))
+            rng=rng)
         self._release_slot(i)
         prefix.slot = -1
         return prefix
@@ -2038,7 +2083,7 @@ class ThinKVEngine:
         pool, table_i, ok = CC.restore_request(
             self.dims, self.pool, jnp.asarray(st.mapped),
             CC.PoolView(*(jnp.asarray(p) for p in st.view)))
-        if not bool(ok):
+        if not bool(self._fetch(ok)):
             self.pool = CC.release_blocks(self.dims, pool, table_i)
             return False
         self.pool = pool
@@ -2081,53 +2126,57 @@ class ThinKVEngine:
         Host token bookkeeping is updated eagerly on the single-tick
         path and deferred to :meth:`consume` on the packed path (the
         host cannot know the executed trip count at dispatch time)."""
-        self._ensure_decode_headroom()
-        active = np.array([not s.free for s in self.scheduler.slots])
+        with TR.span(TR.HEADROOM):
+            self._ensure_decode_headroom()
+            active = np.array([not s.free for s in self.scheduler.slots])
+            if active.any() and self.ticks_per_dispatch > 1:
+                trips = self._safe_decode_trips(
+                    self.ticks_per_dispatch,
+                    [s.idx for s in self.scheduler.active_slots()])
         if not active.any():
             return None, rng
-        # split once per dispatch, exactly like the historical loop —
-        # slot streams own the sampling randomness now, but callers'
-        # rng sequences (and the differential trace suite's decision
-        # order) stay unperturbed
-        rng, _ = jax.random.split(rng)
-        self.metrics["dispatches"] += 1
-        if self.ticks_per_dispatch == 1:
-            (nxt, self.pool, self.tables, self.caches, _, logits,
-             alloc_fail, cow_faults, self._slot_rng) = \
-                self._tick(self.params, self.pool, self.tables,
-                           self.caches, jnp.asarray(self._feed),
-                           jnp.asarray(active), self._slot_rng)
-            self.metrics["ticks"] += 1
-            self.metrics["tokens"] += int(active.sum())
-            self._slot_ntok[active] += 1
-            return ResultTokens(tick=int(self.metrics["ticks"]),
-                                tokens=nxt, valid=active,
-                                lengths=self._slot_ntok.copy(),
-                                logits=logits, alloc_fail=alloc_fail,
-                                cow_faults=cow_faults), rng
-        idx = [s.idx for s in self.scheduler.active_slots()]
-        trips = self._safe_decode_trips(self.ticks_per_dispatch, idx)
-        if trips < self.ticks_per_dispatch:
-            self.metrics["early_exit_headroom"] += 1
-        R = self.cfg.max_seqs
-        remaining = np.zeros(R, np.int32)
-        eos = np.full(R, -1, np.int32)
-        for s in self.scheduler.active_slots():
-            remaining[s.idx] = max(
-                1, int(s.request.max_new_tokens) - int(s.tokens_out))
-            if s.request.eos_token is not None:
-                eos[s.idx] = int(s.request.eos_token)
-        (toks, valid, logits_buf, self.pool, self.tables, self.caches,
-         self._slot_rng, t, fail, cow) = self._megatick(
-            self.params, self.pool, self.tables, self.caches,
-            jnp.asarray(self._feed), jnp.asarray(active),
-            self._slot_rng, jnp.asarray(remaining), jnp.asarray(eos),
-            jnp.int32(trips))
-        return MultiResultTokens(base_tick=int(self.metrics["ticks"]),
-                                 requested=trips, tokens=toks,
-                                 valid=valid, logits=logits_buf,
-                                 alloc_fail=fail, cow_faults=cow,
-                                 trips=t), rng
+        with TR.span(TR.LAUNCH):
+            # split once per dispatch, exactly like the historical loop —
+            # slot streams own the sampling randomness now, but callers'
+            # rng sequences (and the differential trace suite's decision
+            # order) stay unperturbed
+            rng, _ = jax.random.split(rng)
+            self.metrics["dispatches"] += 1
+            if self.ticks_per_dispatch == 1:
+                (nxt, self.pool, self.tables, self.caches, _, logits,
+                 alloc_fail, cow_faults, self._slot_rng) = \
+                    self._tick(self.params, self.pool, self.tables,
+                               self.caches, jnp.asarray(self._feed),
+                               jnp.asarray(active), self._slot_rng)
+                self.metrics["ticks"] += 1
+                self.metrics["tokens"] += int(active.sum())
+                self._slot_ntok[active] += 1
+                return ResultTokens(tick=int(self.metrics["ticks"]),
+                                    tokens=nxt, valid=active,
+                                    lengths=self._slot_ntok.copy(),
+                                    logits=logits, alloc_fail=alloc_fail,
+                                    cow_faults=cow_faults), rng
+            if trips < self.ticks_per_dispatch:
+                self.metrics["early_exit_headroom"] += 1
+            R = self.cfg.max_seqs
+            remaining = np.zeros(R, np.int32)
+            eos = np.full(R, -1, np.int32)
+            for s in self.scheduler.active_slots():
+                remaining[s.idx] = max(
+                    1, int(s.request.max_new_tokens) - int(s.tokens_out))
+                if s.request.eos_token is not None:
+                    eos[s.idx] = int(s.request.eos_token)
+            (toks, valid, logits_buf, self.pool, self.tables, self.caches,
+             self._slot_rng, t, fail, cow) = self._megatick(
+                self.params, self.pool, self.tables, self.caches,
+                jnp.asarray(self._feed), jnp.asarray(active),
+                self._slot_rng, jnp.asarray(remaining), jnp.asarray(eos),
+                jnp.int32(trips))
+            return MultiResultTokens(base_tick=int(self.metrics["ticks"]),
+                                     requested=trips, tokens=toks,
+                                     valid=valid, logits=logits_buf,
+                                     alloc_fail=fail, cow_faults=cow,
+                                     trips=t), rng
 
     def consume(self, res) -> "ResultTokens | MultiResultTokens":
         """Fold a completed dispatch's deferred device flags into the
@@ -2144,31 +2193,34 @@ class ThinKVEngine:
         consumes a pack before the next ``generate``/``prefill`` reads
         any of that state.  COW faults on FORKED slots are attributed
         to ``metrics["fork_cow_faults"]`` (best-of-n divergence cost)."""
-        if res.alloc_fail_host:
-            raise AssertionError(
-                "decode commit allocation failed despite preemption "
-                "headroom (pool accounting bug — data would have been "
-                "dropped)")
-        cow = res.cow_per_slot_host
-        self.metrics["cow_faults"] += int(cow.sum())
-        self.metrics["fork_cow_faults"] += int(cow[self._forked].sum())
-        if res.packed:
-            trips = res.trips_host
-            if trips < res.requested:
-                self.metrics["early_exit_finish"] += 1
-            counts = res.valid_host[:trips].sum(axis=0).astype(np.int64)
-            self.metrics["ticks"] += trips
-            self.metrics["tokens"] += int(counts.sum())
-            self._slot_ntok += counts
-            if self.record_logits:
-                for t in range(trips):
-                    self.trace.append({"kind": "decode",
-                                       "active": res.valid_host[t].copy(),
-                                       "logits": res.logits_host[t]})
-        elif self.record_logits:
-            self.trace.append({"kind": "decode",
-                               "active": res.valid.copy(),
-                               "logits": res.logits_host})
+        with TR.span(TR.CONSUME):
+            if res.alloc_fail_host:
+                raise AssertionError(
+                    "decode commit allocation failed despite preemption "
+                    "headroom (pool accounting bug — data would have been "
+                    "dropped)")
+            cow = res.cow_per_slot_host
+            self.metrics["cow_faults"] += int(cow.sum())
+            self.metrics["fork_cow_faults"] += int(cow[self._forked].sum())
+            if res.packed:
+                trips = res.trips_host
+                if trips < res.requested:
+                    self.metrics["early_exit_finish"] += 1
+                counts = res.valid_host[:trips].sum(axis=0).astype(
+                    np.int64)
+                self.metrics["ticks"] += trips
+                self.metrics["tokens"] += int(counts.sum())
+                self._slot_ntok += counts
+                if self.record_logits:
+                    for t in range(trips):
+                        self.trace.append(
+                            {"kind": "decode",
+                             "active": res.valid_host[t].copy(),
+                             "logits": res.logits_host[t]})
+            elif self.record_logits:
+                self.trace.append({"kind": "decode",
+                                   "active": res.valid.copy(),
+                                   "logits": res.logits_host})
         return res
 
     def fork_slot(self, src: int, dst: int, arrival: int) -> None:
@@ -2207,7 +2259,7 @@ class ThinKVEngine:
         self.metrics["forks"] += 1
         self.metrics["peak_refcount"] = max(
             self.metrics["peak_refcount"],
-            int(np.asarray(self.pool.refcount).max()))
+            int(self._fetch(self.pool.refcount).max()))
 
     def free_resource(self, slot_idx: int) -> None:
         """Release EVERY pool reference slot ``slot_idx`` holds — private
@@ -2260,7 +2312,9 @@ class ThinKVEngine:
         one = jax.tree.map(lambda x: x[i], self.caches)
         from repro.core.thinkv import compression_ratio
         comp = compression_ratio(self.tk, self.dims, one, one.num_tokens)
+        comp, ntok, buf_len, seg = self._fetch(
+            (comp, one.num_tokens, one.buf_len, one.cur_seg))
         out = {k: np.asarray(v).tolist() for k, v in comp.items()}
-        out["committed_tokens"] = int(one.num_tokens - one.buf_len)
-        out["refreshes"] = int(one.cur_seg)
+        out["committed_tokens"] = int(ntok - buf_len)
+        out["refreshes"] = int(seg)
         return out

@@ -65,6 +65,7 @@ from typing import Dict, List, Optional
 import jax
 import numpy as np
 
+from repro.serving import tracing as TR
 from repro.serving.scheduler import Request, RequestState
 
 _END = object()        # stream sentinel: no further tokens
@@ -308,42 +309,59 @@ class Orchestrator:
                 await self._wait_for_arrival()
                 continue
             iters += 1
-            if not any(not s.free for s in sch.slots):
-                await self._admit_and_prefill()
-                if sch.queue and not any(not s.free for s in sch.slots):
-                    # last resort before declaring livelock: unpin
-                    # spilled requests' retained shared references
-                    # (blocks co-held by cache entries + spills deadlock
-                    # decay against preemption) and retry admission once
-                    if eng._demote_spilled_shared():
-                        await self._admit_and_prefill()
-                if sch.queue and not any(not s.free for s in sch.slots):
-                    # nothing running means every claimed block is pinned
-                    # by cache entries/spills the decay valve could not
-                    # release, and the watermark still refuses every
-                    # queued request; with no in-flight request the pool
-                    # can never change, so admission can never succeed
-                    # and nothing is preemptible — fail loudly instead
-                    # of spinning max_ticks and dropping requests
-                    raise RuntimeError(
-                        f"admission livelock: {len(sch.queue)} queued "
-                        f"request(s), nothing running or preemptible, and "
-                        f"the global pool ({eng.num_pool_blocks} blocks) "
-                        f"is below the smallest request's watermark "
-                        f"estimate — the pool cannot serve even one "
-                        f"request")
-                continue
+            with jax.profiler.StepTraceAnnotation(
+                    TR.STEP, step_num=int(eng.metrics["ticks"]) + 1):
+                await self._step()
+        self._drain_retrace_events()   # events from trailing prefills
+        eng.metrics["wall_s"] = time.perf_counter() - self._t0
+        return sch.finished
+
+    async def _step(self) -> None:
+        """One iteration of the serve loop with work queued or running:
+        admit into an empty batch, or dispatch a tick, wait for it,
+        consume it, fan its tokens out and run an admission sweep."""
+        eng = self.engine
+        sch = eng.scheduler
+        if not any(not s.free for s in sch.slots):
+            await self._admit_and_prefill()
+            if sch.queue and not any(not s.free for s in sch.slots):
+                # last resort before declaring livelock: unpin spilled
+                # requests' retained shared references (blocks co-held
+                # by cache entries + spills deadlock decay against
+                # preemption) and retry admission once
+                if eng._demote_spilled_shared():
+                    await self._admit_and_prefill()
+            if sch.queue and not any(not s.free for s in sch.slots):
+                # nothing running means every claimed block is pinned by
+                # cache entries/spills the decay valve could not
+                # release, and the watermark still refuses every queued
+                # request; with no in-flight request the pool can never
+                # change, so admission can never succeed and nothing is
+                # preemptible — fail loudly instead of spinning
+                # max_ticks and dropping requests
+                raise RuntimeError(
+                    f"admission livelock: {len(sch.queue)} queued "
+                    f"request(s), nothing running or preemptible, and "
+                    f"the global pool ({eng.num_pool_blocks} blocks) "
+                    f"is below the smallest request's watermark "
+                    f"estimate — the pool cannot serve even one "
+                    f"request")
+            return
+        with TR.span(TR.DISPATCH):
             res, self._rng = eng.generate(self._rng)
-            if res is None:
-                continue         # headroom preempted everything this round
-            self._log("dispatch", tick=res.tick)
-            # park off-thread while the tick computes + D2H copies land;
-            # consumers woken by the previous iteration's puts run NOW,
-            # so tick N-1's deliveries land inside tick N's window
-            await asyncio.get_running_loop().run_in_executor(None, res.block)
-            eng.consume(res)
-            self._log("consume", tick=res.tick)
-            self._drain_retrace_events()
+        if res is None:
+            return           # headroom preempted everything this round
+        self._log("dispatch", tick=res.tick)
+        # park off-thread while the tick computes + D2H copies land;
+        # consumers woken by the previous iteration's puts run NOW, so
+        # tick N-1's deliveries land inside tick N's window
+        with TR.span(TR.WAIT):
+            await asyncio.get_running_loop().run_in_executor(None,
+                                                             res.block)
+        eng.consume(res)
+        self._log("consume", tick=res.tick)
+        self._drain_retrace_events()
+        with TR.span(TR.DELIVER):
             if getattr(res, "packed", False):
                 # drain the multi-tick pack trip by trip — fan-out order
                 # (and retirement timing) identical to trips separate
@@ -363,11 +381,9 @@ class Orchestrator:
                 toks, logits = res.tokens_host, res.logits_host
                 for slot in sch.active_slots():
                     self._record_logits(slot.request, logits[slot.idx])
-                    self._finish_token(slot, int(toks[slot.idx]), res.tick)
-            await self._admit_and_prefill()
-        self._drain_retrace_events()   # events from trailing prefills
-        eng.metrics["wall_s"] = time.perf_counter() - self._t0
-        return sch.finished
+                    self._finish_token(slot, int(toks[slot.idx]),
+                                       res.tick)
+        await self._admit_and_prefill()
 
     async def _wait_for_arrival(self) -> None:
         self._arrival_event.clear()
@@ -451,52 +467,53 @@ class Orchestrator:
     async def _admit_and_prefill(self) -> None:
         eng = self.engine
         sch = eng.scheduler
-        self._try_forks()
-        # keep admitting while prefill can immediately retire requests
-        while True:
-            if not sch.queue or all(not s.free for s in sch.slots):
-                break       # gate construction syncs device state —
-                            # skip it on the steady-state hot path
-            newly = sch.admit(eng._admission_gate())
-            if not newly:
-                break
-            for slot in newly:
-                req = slot.request
-                if req is None:
-                    continue    # vacated mid-sweep (defensive; started
-                                # slots only — pending ones can't be
-                                # victims, see _victim_exclude)
-                eng.metrics["admissions"] += 1
-                eng.metrics["queue_wait_ticks"] += \
-                    eng.metrics["ticks"] - eng._queued_at.pop(
-                        req.arrival, eng.metrics["ticks"])
-                self._mark_admitted(req)
-                st = eng._spilled.pop(req.arrival, None)
-                if st is not None:
-                    self._log("resume", arrival=req.arrival)
-                    if not eng._resume(slot, st):
-                        # an earlier admission this sweep overclaimed
-                        # past its estimate: re-spill, re-queue, and
-                        # let the next sweep's gate see true counts
-                        eng._spilled[req.arrival] = st
-                        sch.preempt(slot)
-                        eng._queued_at[req.arrival] = eng.metrics["ticks"]
-                    continue
-                # yield once so running requests' consumers drain while
-                # this prefill dispatches (prefill overlaps decode)
-                await asyncio.sleep(0)
-                self._log("prefill", arrival=req.arrival,
-                          decoding=sum(1 for s in sch.active_slots()
-                                       if s is not slot
-                                       and s.tokens_out > 0))
-                prefix, self._rng = eng.prefill(req.prompt, slot.idx,
-                                                self._rng,
-                                                arrival=req.arrival)
-                eng.insert(prefix, slot.idx)
-                self._record_logits(req, prefix.logits)
-                self._finish_token(slot, prefix.first_token,
-                                   int(eng.metrics["ticks"]))
-        self._try_forks()
+        with TR.span(TR.ADMIT):
+            self._try_forks()
+            # keep admitting while prefill can immediately retire requests
+            while True:
+                if not sch.queue or all(not s.free for s in sch.slots):
+                    break       # gate construction syncs device state —
+                                # skip it on the steady-state hot path
+                newly = sch.admit(eng._admission_gate())
+                if not newly:
+                    break
+                for slot in newly:
+                    req = slot.request
+                    if req is None:
+                        continue    # vacated mid-sweep (defensive; started
+                                    # slots only — pending ones can't be
+                                    # victims, see _victim_exclude)
+                    eng.metrics["admissions"] += 1
+                    eng.metrics["queue_wait_ticks"] += \
+                        eng.metrics["ticks"] - eng._queued_at.pop(
+                            req.arrival, eng.metrics["ticks"])
+                    self._mark_admitted(req)
+                    st = eng._spilled.pop(req.arrival, None)
+                    if st is not None:
+                        self._log("resume", arrival=req.arrival)
+                        if not eng._resume(slot, st):
+                            # an earlier admission this sweep overclaimed
+                            # past its estimate: re-spill, re-queue, and
+                            # let the next sweep's gate see true counts
+                            eng._spilled[req.arrival] = st
+                            sch.preempt(slot)
+                            eng._queued_at[req.arrival] = eng.metrics["ticks"]
+                        continue
+                    # yield once so running requests' consumers drain while
+                    # this prefill dispatches (prefill overlaps decode)
+                    await asyncio.sleep(0)
+                    self._log("prefill", arrival=req.arrival,
+                              decoding=sum(1 for s in sch.active_slots()
+                                           if s is not slot
+                                           and s.tokens_out > 0))
+                    prefix, self._rng = eng.prefill(req.prompt, slot.idx,
+                                                    self._rng,
+                                                    arrival=req.arrival)
+                    eng.insert(prefix, slot.idx)
+                    self._record_logits(req, prefix.logits)
+                    self._finish_token(slot, prefix.first_token,
+                                       int(eng.metrics["ticks"]))
+            self._try_forks()
 
     def _adopt_existing(self) -> None:
         """Requests submitted straight to the engine (``engine.submit``)
