@@ -94,10 +94,10 @@ def paged_decode_attention_fused(qh, k_codes, v_codes, k_scales, v_scales,
 # per-shard launch plumbing (tensor-parallel serving over the KV-head axis)
 # ---------------------------------------------------------------------------
 # Inside the engine's ``shard_map``, each device launches the SAME fused
-# kernel over its contiguous slice of KV heads: the grid axes are
-# (layer, request, head, block), and no kernel step reads across heads, so
-# a per-shard launch over H/n heads computes exactly the corresponding
-# slice of the single-device launch.  This slice (going in) plus
+# kernel over its contiguous slice of KV heads: no kernel reads across
+# heads (a fused grid step loops over its heads, each with its own
+# scores and values), so a per-shard launch over H/n heads computes
+# exactly the corresponding slice of the single-device launch.  This slice (going in) plus
 # ``core.ct_cache.gather_heads`` (attention outputs coming back out) are
 # the only sharding the kernel entry points ever see — pure data
 # movement; the per-head math is untouched, keeping sharded runs

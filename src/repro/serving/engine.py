@@ -340,17 +340,19 @@ class ResultTokens:
 
     Wraps the device arrays one fused decode tick produced — next tokens
     [R], per-slot validity [R], generated-so-far lengths [R], last-token
-    logits [R, V], plus the deferred commit-failure flag and COW-fault
-    count — and starts their D2H copies IMMEDIATELY at construction, so
-    the transfer overlaps whatever the host dispatches next (the next
-    tick, a prefill chunk).  Nothing blocks until :meth:`block` (or the
-    ``*_host`` properties), which the orchestrator calls from an executor
-    thread while the asyncio loop keeps streaming."""
+    logits [R, V], plus the deferred commit-failure flag, COW-fault count
+    and the count of mapped pages its attention read — and starts their
+    D2H copies IMMEDIATELY at construction, so the transfer overlaps
+    whatever the host dispatches next (the next tick, a prefill chunk).
+    Nothing blocks until :meth:`block` (or the ``*_host`` properties),
+    which the orchestrator calls from an executor thread while the
+    asyncio loop keeps streaming."""
 
     packed = False                       # one tick per result
 
     def __init__(self, tick: int, tokens, valid: np.ndarray,
-                 lengths: np.ndarray, logits, alloc_fail, cow_faults):
+                 lengths: np.ndarray, logits, alloc_fail, cow_faults,
+                 attn_pages):
         self.tick = tick                 # 1-based tick index of this result
         self.valid = valid               # [R] bool (host — scheduler truth)
         self.lengths = lengths           # [R] tokens generated AFTER this
@@ -358,8 +360,9 @@ class ResultTokens:
         self._logits = logits            # [R, V] (device)
         self._alloc_fail = alloc_fail
         self._cow_faults = cow_faults
+        self._attn_pages = attn_pages    # int32 scalar (device)
         self._host = None
-        for x in (tokens, logits, alloc_fail, cow_faults):
+        for x in (tokens, logits, alloc_fail, cow_faults, attn_pages):
             if hasattr(x, "copy_to_host_async"):
                 x.copy_to_host_async()
 
@@ -370,9 +373,10 @@ class ResultTokens:
                 tokens = np.asarray(self._tokens)
                 fail = bool(np.any(np.asarray(self._alloc_fail)))
                 cow = np.asarray(self._cow_faults).astype(np.int64)
+                pages = int(np.asarray(self._attn_pages))
             with TR.span(TR.FETCH_LOGITS):
                 logits = np.asarray(self._logits)
-            self._host = (tokens, logits, fail, int(cow.sum()), cow)
+            self._host = (tokens, logits, fail, int(cow.sum()), cow, pages)
         return self
 
     @property
@@ -397,6 +401,12 @@ class ResultTokens:
         faults to forked slots (best-of-n divergence accounting)."""
         return self.block()._host[4]
 
+    @property
+    def attn_pages_host(self) -> int:
+        """Mapped pool pages the tick's attention read through its
+        tables (the pages the fused kernel fetches)."""
+        return self.block()._host[5]
+
 
 class MultiResultTokens:
     """Packed MULTI-tick result of one mega-dispatch (``packed=True``).
@@ -406,8 +416,9 @@ class MultiResultTokens:
     produced — per-trip tokens ``[N, R]``, per-trip slot validity
     ``[N, R]`` (a slot that finished via EOS/length inside the pack is
     invalid from the NEXT trip on), per-trip logits ``[N, R, V]``, the
-    per-slot COW-fault counts, the OR'd allocation-failure flag, and the
-    trip count the loop actually executed (``trips_host < requested``
+    per-slot COW-fault counts, the OR'd allocation-failure flag, the
+    mapped pages its trips' attention read, and the trip count the loop
+    actually executed (``trips_host < requested``
     means a scheduling event — a slot finishing — exited the loop
     early).  Rows ``trips_host..N-1`` of every buffer are zero-filled
     and must be ignored.
@@ -424,7 +435,7 @@ class MultiResultTokens:
     packed = True
 
     def __init__(self, base_tick: int, requested: int, tokens, valid,
-                 logits, alloc_fail, cow_faults, trips):
+                 logits, alloc_fail, cow_faults, trips, attn_pages):
         self.base_tick = base_tick       # metrics["ticks"] at dispatch
         self.tick = base_tick + 1        # first fused tick (dispatch log)
         self.requested = requested       # host-precomputed safe trip cap
@@ -434,8 +445,10 @@ class MultiResultTokens:
         self._alloc_fail = alloc_fail
         self._cow_faults = cow_faults    # [R] per-slot (device)
         self._trips = trips              # int32 scalar (device)
+        self._attn_pages = attn_pages    # int32 scalar, summed over trips
         self._host = None
-        for x in (tokens, valid, logits, alloc_fail, cow_faults, trips):
+        for x in (tokens, valid, logits, alloc_fail, cow_faults, trips,
+                  attn_pages):
             if hasattr(x, "copy_to_host_async"):
                 x.copy_to_host_async()
 
@@ -448,9 +461,10 @@ class MultiResultTokens:
                 fail = bool(np.any(np.asarray(self._alloc_fail)))
                 cow = np.asarray(self._cow_faults).astype(np.int64)
                 trips = int(np.asarray(self._trips))
+                pages = int(np.asarray(self._attn_pages))
             with TR.span(TR.FETCH_LOGITS):
                 logits = np.asarray(self._logits)
-            self._host = (tokens, valid, logits, fail, cow, trips)
+            self._host = (tokens, valid, logits, fail, cow, trips, pages)
         return self
 
     @property
@@ -480,6 +494,10 @@ class MultiResultTokens:
     @property
     def trips_host(self) -> int:
         return self.block()._host[5]
+
+    @property
+    def attn_pages_host(self) -> int:
+        return self.block()._host[6]
 
 
 class ThinKVEngine:
@@ -631,7 +649,9 @@ class ThinKVEngine:
                                           "cancellations": 0,
                                           "drift_probes": 0,
                                           "drift_max_abs": 0.0,
-                                          "host_syncs": 0}
+                                          "host_syncs": 0,
+                                          "attn_pages": 0,
+                                          "attn_page_slots": 0}
         from repro.serving.prefix_cache import PrefixCache
         self.prefix_cache = PrefixCache(
             self.dims, capacity=prefix_cache_capacity) \
@@ -805,6 +825,9 @@ class ThinKVEngine:
                     (jnp.arange(cfg.num_layers), params["layers"]))
             caches = caches.replace(buf_k=buf_k, buf_v=buf_v)
             n_buf = buf_len + 1                                  # [R]
+            # mapped pool pages the attention reads: the fused kernel
+            # fetches these and skips the unmapped (-1) table entries
+            attn_pages = jnp.sum(tables >= 0, dtype=jnp.int32)
             # queries of this shard's kv heads ([L, R, Hq/N, hd]; the Hq
             # axis is kv-head-major, so the slice is contiguous)
             qs_loc = self._local_heads(qs, 2)
@@ -913,7 +936,7 @@ class ThinKVEngine:
                 logits = softcap(E.unembed(params["embed"], h, cfg),
                                  cfg.logit_softcap)              # [R, V]
             return (pool, tables_out, caches, sparsity, logits,
-                    alloc_fail, cow_faults)
+                    alloc_fail, cow_faults, attn_pages)
 
         return tick_core
 
@@ -928,19 +951,20 @@ class ThinKVEngine:
 
         def tick(params, pool, tables, caches, tokens, active, slot_rngs):
             (pool, tables_out, caches, sparsity, logits, alloc_fail,
-             cow_faults) = core(params, pool, tables, caches, tokens,
-                                active)
+             cow_faults, attn_pages) = core(params, pool, tables, caches,
+                                            tokens, active)
             with TR.scope(TR.SAMPLE):
                 nxt, slot_rngs = _sample_slots(slot_rngs, logits, temp,
                                                top_p)
             return (nxt, pool, tables_out, caches, sparsity, logits,
-                    alloc_fail, cow_faults, slot_rngs)
+                    alloc_fail, cow_faults, slot_rngs, attn_pages)
 
         pool_s, cache_s, rep = self._spmd_specs(single_request=False)
         return self._wrap_spmd(
             tick,
             in_specs=(rep, pool_s, rep, cache_s, rep, rep, rep),
-            out_specs=(rep, pool_s, rep, cache_s, rep, rep, rep, rep, rep))
+            out_specs=(rep, pool_s, rep, cache_s, rep, rep, rep, rep, rep,
+                       rep))
 
     def _make_megatick(self):
         """Fuse up to ``ticks_per_dispatch`` decode ticks in ONE
@@ -980,9 +1004,11 @@ class ThinKVEngine:
 
             def body(c):
                 (t, pool, tables, caches, tokens, active, slot_rngs,
-                 produced, toks, valid, logits_buf, fail, _stop, cow) = c
-                (pool, tables, caches, _, logits, fail_t, cow_t) = core(
-                    params, pool, tables, caches, tokens, active)
+                 produced, toks, valid, logits_buf, fail, _stop, cow,
+                 pages) = c
+                (pool, tables, caches, _, logits, fail_t, cow_t,
+                 pages_t) = core(params, pool, tables, caches, tokens,
+                                 active)
                 with TR.scope(TR.SAMPLE):
                     nxt, slot_rngs = _sample_slots(slot_rngs, logits, temp,
                                                    top_p)
@@ -995,7 +1021,7 @@ class ThinKVEngine:
                 return (t + 1, pool, tables, caches, nxt, active & ~done,
                         slot_rngs, produced, toks, valid, logits_buf,
                         fail | jnp.any(fail_t), jnp.any(done),
-                        cow + cow_t.astype(jnp.int32))
+                        cow + cow_t.astype(jnp.int32), pages + pages_t)
 
             init = (jnp.int32(0), pool, tables, caches, tokens, active,
                     slot_rngs, jnp.zeros(R, jnp.int32),
@@ -1003,12 +1029,12 @@ class ThinKVEngine:
                     jnp.zeros((N, R), bool),
                     jnp.zeros((N, R, V), jnp.float32),
                     jnp.bool_(False), jnp.bool_(False),
-                    jnp.zeros(R, jnp.int32))
+                    jnp.zeros(R, jnp.int32), jnp.int32(0))
             (t, pool, tables, caches, _, _, slot_rngs, _, toks, valid,
-             logits_buf, fail, _, cow) = jax.lax.while_loop(cond, body,
-                                                            init)
+             logits_buf, fail, _, cow, pages) = jax.lax.while_loop(
+                 cond, body, init)
             return (toks, valid, logits_buf, pool, tables, caches,
-                    slot_rngs, t, fail, cow)
+                    slot_rngs, t, fail, cow, pages)
 
         pool_s, cache_s, rep = self._spmd_specs(single_request=False)
         return self._wrap_spmd(
@@ -1016,7 +1042,7 @@ class ThinKVEngine:
             in_specs=(rep, pool_s, rep, cache_s, rep, rep, rep, rep, rep,
                       rep),
             out_specs=(rep, rep, rep, pool_s, rep, cache_s, rep, rep, rep,
-                       rep))
+                       rep, rep))
 
     # ------------------------------------------------------------------
     def _make_prefill_chunk(self):
@@ -2144,7 +2170,7 @@ class ThinKVEngine:
             self.metrics["dispatches"] += 1
             if self.ticks_per_dispatch == 1:
                 (nxt, self.pool, self.tables, self.caches, _, logits,
-                 alloc_fail, cow_faults, self._slot_rng) = \
+                 alloc_fail, cow_faults, self._slot_rng, pages) = \
                     self._tick(self.params, self.pool, self.tables,
                                self.caches, jnp.asarray(self._feed),
                                jnp.asarray(active), self._slot_rng)
@@ -2155,7 +2181,8 @@ class ThinKVEngine:
                                     tokens=nxt, valid=active,
                                     lengths=self._slot_ntok.copy(),
                                     logits=logits, alloc_fail=alloc_fail,
-                                    cow_faults=cow_faults), rng
+                                    cow_faults=cow_faults,
+                                    attn_pages=pages), rng
             if trips < self.ticks_per_dispatch:
                 self.metrics["early_exit_headroom"] += 1
             R = self.cfg.max_seqs
@@ -2167,7 +2194,7 @@ class ThinKVEngine:
                 if s.request.eos_token is not None:
                     eos[s.idx] = int(s.request.eos_token)
             (toks, valid, logits_buf, self.pool, self.tables, self.caches,
-             self._slot_rng, t, fail, cow) = self._megatick(
+             self._slot_rng, t, fail, cow, pages) = self._megatick(
                 self.params, self.pool, self.tables, self.caches,
                 jnp.asarray(self._feed), jnp.asarray(active),
                 self._slot_rng, jnp.asarray(remaining), jnp.asarray(eos),
@@ -2176,7 +2203,7 @@ class ThinKVEngine:
                                      requested=trips, tokens=toks,
                                      valid=valid, logits=logits_buf,
                                      alloc_fail=fail, cow_faults=cow,
-                                     trips=t), rng
+                                     trips=t, attn_pages=pages), rng
 
     def consume(self, res) -> "ResultTokens | MultiResultTokens":
         """Fold a completed dispatch's deferred device flags into the
@@ -2192,7 +2219,10 @@ class ThinKVEngine:
         single-tick results.  Safe to defer because the orchestrator
         consumes a pack before the next ``generate``/``prefill`` reads
         any of that state.  COW faults on FORKED slots are attributed
-        to ``metrics["fork_cow_faults"]`` (best-of-n divergence cost)."""
+        to ``metrics["fork_cow_faults"]`` (best-of-n divergence cost).
+        ``metrics["attn_pages"]`` adds the mapped pages the dispatch's
+        attention read, ``metrics["attn_page_slots"]`` the table entries
+        it spanned (slots x layers x NB per tick)."""
         with TR.span(TR.CONSUME):
             if res.alloc_fail_host:
                 raise AssertionError(
@@ -2202,8 +2232,11 @@ class ThinKVEngine:
             cow = res.cow_per_slot_host
             self.metrics["cow_faults"] += int(cow.sum())
             self.metrics["fork_cow_faults"] += int(cow[self._forked].sum())
+            trips = res.trips_host if res.packed else 1
+            self.metrics["attn_pages"] += res.attn_pages_host
+            self.metrics["attn_page_slots"] += trips * int(
+                np.prod(self.tables.shape))
             if res.packed:
-                trips = res.trips_host
                 if trips < res.requested:
                     self.metrics["early_exit_finish"] += 1
                 counts = res.valid_host[:trips].sum(axis=0).astype(

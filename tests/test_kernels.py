@@ -271,6 +271,121 @@ def test_ct_paged_attention_fused_is_one_launch(rng):
     assert ops.count_pallas_launches(jaxpr) == 1
 
 
+#: table rows left unmapped, as {(request, layer): logical blocks mapped}
+#: (every other row maps all NB = 8 blocks)
+LAYOUTS = {
+    "full": {},
+    "holes": {(0, 0): (0, 2, 3, 5, 7), (0, 1): (0, 2, 3, 5, 7)},
+    "empty_row": {(1, 1): ()},
+    "empty_chunk": {(0, 0): (6, 7), (1, 1): (0,)},
+}
+SPARE = 3                         # physical blocks no table maps
+
+
+@pytest.fixture(scope="module")
+def paged_case():
+    """Fused-kernel inputs over a shuffled physical pool with SPARE
+    unmapped blocks, physical block 0 among them (the block the old grid
+    clamped ``-1`` to): ``(qh, args, poisoned planes)``."""
+    rng = np.random.default_rng(7)
+    dims, args = _fused_args(rng, layers=2, precision=(2, 4, 8))
+    L, NB = dims.L, dims.NB
+    phys = 1 + rng.permutation(NB + SPARE - 1)[:NB]
+    pool, poisoned = {}, {}
+    for name in ("k_codes", "v_codes", "k_scales", "v_scales"):
+        a = np.asarray(args[name].astype(jnp.float32))
+        out = np.zeros((L, NB + SPARE) + a.shape[2:], np.float32)
+        out[:, phys] = a
+        bad = out.copy()
+        spare = np.setdiff1d(np.arange(NB + SPARE), phys)
+        if "scales" in name:
+            bad[:, spare] = np.nan
+        else:
+            bad[:, spare] = rng.integers(0, 256, bad[:, spare].shape)
+        dt = args[name].dtype
+        pool[name], poisoned[name] = jnp.asarray(out, dt), jnp.asarray(bad,
+                                                                       dt)
+    args = dict(args, **pool,
+                block_table=jnp.broadcast_to(jnp.asarray(phys, jnp.int32),
+                                             args["block_table"].shape))
+    qh = jnp.asarray(rng.standard_normal((L, 2, dims.H, 2, dims.D)),
+                     jnp.float32)
+    return qh, args, poisoned
+
+
+def _with_layout(args, layout):
+    """``args`` with the rows of ``LAYOUTS[layout]`` partly unmapped: the
+    dropped blocks read ``-1`` and their slots FREE."""
+    table = np.asarray(args["block_table"]).copy()
+    state = np.asarray(args["slot_state"]).copy()
+    for (r, l), keep in LAYOUTS[layout].items():
+        drop = np.setdiff1d(np.arange(table.shape[-1]), keep)
+        table[r, l, drop] = -1
+        state[l, r, drop] = 0
+    return dict(args, block_table=jnp.asarray(table),
+                slot_state=jnp.asarray(state))
+
+
+def _fused(qh, args, pages):
+    """The public entry point (chunk size from shapes) or, with
+    ``pages``, the launch at that chunk size."""
+    from repro.kernels import ct_paged_attention as CPA
+    if pages is None:
+        return CPA.ct_paged_attention_fused(qh, **args, group=16,
+                                            interpret=True)
+    launch = jax.jit(CPA._fused_launch,
+                     static_argnames=("group", "pages", "interpret"))
+    return launch(qh, **args, group=16, pages=pages, interpret=True)
+
+
+@pytest.mark.parametrize("layout,pages", [
+    ("holes", 3),            # holes mid-row; NB = 8 is not a multiple of 3
+    ("holes", None),         # the derived chunk: every page in one step
+    ("full", 3),             # every block mapped, a short last chunk
+    ("empty_row", 2),        # one (request, layer) row maps nothing
+    ("empty_chunk", 2),      # rows whose later chunks hold no mapped page
+])
+def test_ct_paged_attention_fused_table_layouts_vs_ref(paged_case, layout,
+                                                       pages):
+    """Multi-page grid steps over mapped-first rows: any set of mapped
+    blocks, in any positions and any chunking, attends as the layered
+    reference does; a row with nothing mapped attends its buffer only."""
+    qh, args, _ = paged_case
+    args = _with_layout(args, layout)
+    o_k = np.asarray(_fused(qh, args, pages))
+    o_r = np.asarray(R.ct_paged_attention_fused_ref(qh, **args, group=16))
+    np.testing.assert_allclose(o_k, o_r, rtol=1e-4, atol=1e-4)
+    if layout == "empty_row":
+        o_b, _, _ = R.buffer_attention_batched_ref(
+            qh[1], jnp.swapaxes(args["buf_k"][1], 1, 2),
+            jnp.swapaxes(args["buf_v"][1], 1, 2), args["buf_len"])
+        np.testing.assert_allclose(o_k[1, 1], np.asarray(o_b[1]),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_ct_paged_attention_fused_never_reads_unmapped_blocks(paged_case):
+    """NaN scales in physical block 0 and every other block no table maps
+    leave the output finite and equal to the reference over the clean
+    pool: unmapped pages are neither fetched nor let through unmasked
+    from stale VMEM.  (The reference itself clamps ``-1`` to block 0, so
+    it is computed over the clean pool.)"""
+    qh, args, poisoned = paged_case
+    args = _with_layout(args, "holes")
+    o_r = np.asarray(R.ct_paged_attention_fused_ref(qh, **args, group=16))
+    o_k = np.asarray(_fused(qh, dict(args, **poisoned), 3))
+    assert np.isfinite(o_k).all()
+    np.testing.assert_allclose(o_k, o_r, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("heads,pages", [(4, 16), (1, 64), (8, 8)])
+def test_fused_chunk_is_derived_from_shapes(heads, pages):
+    """At head_dim 128, g 16, BS 16 and NB 64: qwen2-7b's and yi-6b's 4
+    kv heads, one head of a 4-way shard, and 8 kv heads."""
+    from repro.kernels.ct_paged_attention import pages_per_step
+    assert pages_per_step(64, heads, 16, 128, 8) == pages
+    assert pages_per_step(5, heads, 16, 128, 8) <= 5
+
+
 def test_batched_entry_accepts_raw_tables(rng):
     """Entry points clamp -1 sentinels internally: a raw table with
     unmapped (all-FREE) blocks matches the pre-clamped call."""

@@ -72,6 +72,9 @@ def test_mega_dispatch_greedy_parity_and_dispatch_amortization(rng):
     decoded = eng8.metrics["tokens"]
     assert eng8.metrics["dispatches"] / decoded < 1.0
     assert eng8.metrics["ticks"] / eng8.metrics["dispatches"] > 1.0
+    # a pack sums the mapped pages its trips read, as single ticks do
+    for key in ("attn_pages", "attn_page_slots"):
+        assert eng8.metrics[key] == eng1.metrics[key] > 0, key
 
 
 def test_mega_dispatch_temperature_parity(rng):

@@ -1,5 +1,6 @@
 """Compile the main path's kernels for a described TPU v5e at qwen2-7b
-widths (28 query heads, 4 kv heads, head_dim 128), with no chip attached.
+widths (28 query heads, 4 kv heads, head_dim 128), and the fused decode
+kernel at yi-6b's too (32 over 4), with no chip attached.
 
 Nothing runs: a compile that passes says the TPU compiler accepts the
 kernels' block shapes, layouts and memory use.  The topology is described
@@ -44,8 +45,8 @@ def one_chip(topo):
     compilation_cache.reset_cache()
 
 
-def _fused_args(s, heads):
-    return (s((L, R, heads, GQ, D), jnp.float32),
+def _fused_args(s, heads, gq=GQ):
+    return (s((L, R, heads, gq, D), jnp.float32),
             s((L, NP, heads, BS, D), jnp.uint8),
             s((L, NP, heads, BS, D), jnp.uint8),
             s((L, NP, heads, BS, D // 16), jnp.bfloat16),
@@ -79,6 +80,9 @@ CASES = {
     # one tensor-parallel shard of the tick at --mesh model=4
     "fused_one_head": (lambda *a: ct_paged_attention_fused(*a, group=16),
                        lambda s: _fused_args(s, 1)),
+    # yi-6b's 32 query heads over 4 kv heads: another q-group width
+    "fused_yi6b": (lambda *a: ct_paged_attention_fused(*a, group=16),
+                   lambda s: _fused_args(s, H, gq=8)),
     # big-chunk prefill: frozen-pool partition
     "batched_prefill_fold": (
         lambda *a: ct_paged_attention_batched(*a, group=16), _batched_args),

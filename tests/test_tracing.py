@@ -148,6 +148,29 @@ def test_host_syncs_count_one_read_per_commit_due_headroom_check(served):
     assert syncs == [1 if t in due else 0 for t in range(1, 17)]
 
 
+def test_attn_pages_count_the_mapped_entries_each_tick_reads(served):
+    eng, _, _ = served
+    rng = np.random.default_rng(2)
+    key = jax.random.PRNGKey(1)
+    eng.submit([rng.integers(0, 256, n) for n in (6, 11)],
+               max_new_tokens=40)
+    for slot in eng.scheduler.admit(eng._admission_gate()):
+        prefix, key = eng.prefill(slot.request.prompt, slot.idx, key)
+        eng.insert(prefix, slot.idx)
+        slot.tokens_out += 1
+    before = dict(eng.metrics)
+    mapped = []
+    for _ in range(6):
+        mapped.append(int((np.asarray(eng.tables) >= 0).sum()))
+        res, key = eng.generate(key)
+        eng.consume(res)
+    assert eng.metrics["attn_pages"] - before["attn_pages"] == sum(mapped)
+    assert eng.metrics["attn_page_slots"] - before["attn_page_slots"] == \
+        6 * eng.tables.size
+    # the slots hold a few pages each; most table entries are unmapped
+    assert 0 < sum(mapped) < 6 * eng.tables.size // 2
+
+
 def test_tick_scopes_are_metadata_only(served, monkeypatch):
     eng, _, _ = served
     fn, args = eng.compiled_entry_points()["_tick_fn"]
