@@ -1,4 +1,6 @@
 from repro.config.base import (
+    FULL,
+    WINDOW,
     ArchFamily,
     InputShape,
     MeshConfig,
@@ -6,6 +8,7 @@ from repro.config.base import (
     MoEConfig,
     OptimizerConfig,
     PositionEmbedding,
+    RopeConfig,
     SHAPES,
     ServeConfig,
     SSMConfig,
@@ -18,8 +21,9 @@ from repro.config.base import (
 )
 
 __all__ = [
-    "ArchFamily", "InputShape", "MeshConfig", "ModelConfig", "MoEConfig",
-    "OptimizerConfig", "PositionEmbedding", "SHAPES", "ServeConfig",
-    "SSMConfig", "ThinKVConfig", "ThoughtType", "TrainConfig",
+    "FULL", "WINDOW", "ArchFamily", "InputShape", "MeshConfig",
+    "ModelConfig", "MoEConfig", "OptimizerConfig", "PositionEmbedding",
+    "RopeConfig", "SHAPES", "ServeConfig", "SSMConfig", "ThinKVConfig",
+    "ThoughtType", "TrainConfig",
     "config_to_dict", "reduced", "shape_cells",
 ]

@@ -38,7 +38,7 @@ class PositionEmbedding(str, enum.Enum):
 
 @dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int = 8
+    num_experts: int = 8                  # the router's width (published)
     num_experts_per_token: int = 2
     capacity_factor: float = 1.25
     # token group size for the one-hot dispatch einsum (GShard-style);
@@ -46,6 +46,50 @@ class MoEConfig:
     dispatch_group: int = 256
     router_jitter: float = 0.0
     aux_loss_weight: float = 0.01
+    # experts this device holds, ``(first, stop)``; () holds all.  The
+    # router still spans every expert; tokens routed to experts held
+    # elsewhere add nothing here (``layers.moe.moe_held``).
+    held: Tuple[int, ...] = ()
+
+    def __post_init__(self):
+        if self.held:
+            a, b = self.held
+            if not 0 <= a < b <= self.num_experts:
+                raise ValueError(f"held experts {self.held} outside "
+                                 f"[0, {self.num_experts})")
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        return tuple(self.held) if self.held else (0, self.num_experts)
+
+    @property
+    def num_held(self) -> int:
+        a, b = self.held_range
+        return b - a
+
+
+@dataclass(frozen=True)
+class RopeConfig:
+    """Rotary embedding of one layer kind: plain (``kind="default"``) or
+    YaRN (``"yarn"``: per-frequency blend of interpolated and
+    extrapolated inverse frequencies, cos/sin scaled by
+    ``attention_factor``; Hugging Face's ``_compute_yarn_parameters``)."""
+
+    theta: float = 1e4
+    kind: str = "default"
+    factor: float = 1.0
+    original_max_position: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def __post_init__(self):
+        if self.kind not in ("default", "yarn"):
+            raise ValueError(f"unknown rope kind {self.kind!r}")
+
+
+#: Layer kinds of :attr:`ModelConfig.layer_kinds`.
+FULL, WINDOW = "full", "window"
 
 
 @dataclass(frozen=True)
@@ -81,7 +125,16 @@ class ModelConfig:
     norm_eps: float = 1e-5
     rope_theta: float = 1e4
     position_embedding: PositionEmbedding = PositionEmbedding.ROPE
-    sliding_window: int = 0               # 0 -> disabled (mixtral: 4096)
+    sliding_window: int = 0               # window of WINDOW layers
+    # per-layer attention kind (FULL / WINDOW), from Hugging Face's
+    # ``layer_types``; () is every layer FULL.  A window layer's query at
+    # position p attends p - sliding_window + 1 .. p.
+    layer_kinds: Tuple[str, ...] = ()
+    # rope of FULL layers; None is plain rope at ``rope_theta``, which
+    # WINDOW layers always use
+    rope_full: Optional[RopeConfig] = None
+    # per-head RMSNorm of q and k before rope (Qwen3-style decoder layer)
+    qk_norm: bool = False
     act: str = "silu"                     # mlp activation ("silu"|"gelu")
     mlp_gated: bool = True                # SwiGLU vs plain MLP
     moe: Optional[MoEConfig] = None
@@ -104,6 +157,34 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim == 0 and self.num_heads > 0:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        if self.layer_kinds:
+            if len(self.layer_kinds) != self.num_layers:
+                raise ValueError(f"{len(self.layer_kinds)} layer kinds for "
+                                 f"{self.num_layers} layers")
+            bad = set(self.layer_kinds) - {FULL, WINDOW}
+            if bad:
+                raise ValueError(f"unknown layer kinds {sorted(bad)}")
+            if WINDOW in self.layer_kinds and self.sliding_window <= 0:
+                raise ValueError("window layers need sliding_window > 0")
+
+    # ---- layer kinds --------------------------------------------------------
+    def kind_of(self, layer: int) -> str:
+        return self.layer_kinds[layer] if self.layer_kinds else FULL
+
+    @property
+    def window_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i in range(self.num_layers)
+                     if self.kind_of(i) == WINDOW)
+
+    @property
+    def full_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i in range(self.num_layers)
+                     if self.kind_of(i) == FULL)
+
+    def rope_of(self, kind: str) -> RopeConfig:
+        if kind == FULL and self.rope_full is not None:
+            return self.rope_full
+        return RopeConfig(theta=self.rope_theta)
 
     # ---- derived quantities -------------------------------------------------
     @property
@@ -343,7 +424,15 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         name=cfg.name + "-smoke",
     )
     if cfg.moe is not None:
-        kw["moe"] = replace(cfg.moe, num_experts=4, dispatch_group=64)
+        kw["moe"] = replace(
+            cfg.moe, num_experts=max(4, 2 * cfg.moe.num_experts_per_token),
+            dispatch_group=64, held=())
+    if cfg.layer_kinds:
+        # one whole period of the kinds, with a window tests reach
+        period = cfg.layer_kinds.index(FULL) + 1
+        kw["num_layers"] = period
+        kw["layer_kinds"] = cfg.layer_kinds[:period]
+        kw["sliding_window"] = min(cfg.sliding_window, 16)
     if cfg.ssm is not None:
         kw["ssm"] = replace(cfg.ssm, state_size=min(cfg.ssm.state_size, 16),
                             head_dim=16, chunk_size=16)

@@ -41,6 +41,11 @@ Data model (this PR's paged refactor):
   fresh block, copies the planes, and decrefs the shared source
   (:func:`sync_block_tables` with a dirty mask / :func:`cow_blocks`).
 
+* Window layers (a model with ``layer_kinds``) keep no pool state: each
+  slot's :class:`CTCache` holds a bf16 ring of the last ``W`` keys and
+  values per window layer (:func:`ring`), and the pool, its tables and
+  the metadata planes span the full layers only (:func:`layer_map`).
+
 All state is fixed-shape and jit/vmap friendly.  Functions here operate on a
 SINGLE request with all attention layers stacked on the leading axis; the
 serving engine vmaps/scans over request slots.
@@ -52,7 +57,7 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.config import ThinKVConfig, ThoughtType
+from repro.config import FULL, WINDOW, ThinKVConfig, ThoughtType
 from repro.core import quantization as Q
 from repro.core.policy import get_policy
 from repro.core.thoughts import classify
@@ -77,6 +82,8 @@ class CacheDims(NamedTuple):
     S: int          # max segments
     nibble: bool    # True: 4-bit plane (2 codes/byte would be packed on HBM;
                     # we keep one code per uint8 lane and account 4 bits)
+    LW: int = 0     # window layers, each a ring of W positions per slot
+    W: int = 0      # window (positions a window layer's query attends)
 
     @property
     def NS(self) -> int:
@@ -88,12 +95,28 @@ class CacheDims(NamedTuple):
 
 
 def make_dims(cfg: ThinKVConfig, num_layers: int, kv_heads: int,
-              head_dim: int, slack: float = 2.0) -> CacheDims:
+              head_dim: int, slack: float = 2.0, window_layers: int = 0,
+              window: int = 0) -> CacheDims:
+    """``num_layers`` are the layers the paged pool holds (the full
+    attention layers); ``window_layers`` more keep a bf16 ring of the
+    last ``window`` keys and values per slot."""
     nb = max(int(cfg.token_budget * slack) // cfg.block_size, 4)
     nibble = max(cfg.precision) <= 4
     return CacheDims(L=num_layers, NB=nb, BS=cfg.block_size, H=kv_heads,
                      D=head_dim, G=cfg.group_size, S=cfg.max_segments,
-                     nibble=nibble)
+                     nibble=nibble, LW=window_layers,
+                     W=window if window_layers else 0)
+
+
+def layer_map(layer_kinds) -> Tuple[int, ...]:
+    """Where each model layer keeps its keys and values: its index among
+    the pool's layers for a full layer, among the rings' for a window
+    layer."""
+    index, seen = [], {FULL: 0, WINDOW: 0}
+    for kind in layer_kinds:
+        index.append(seen[kind])
+        seen[kind] += 1
+    return tuple(index)
 
 
 # ---------------------------------------------------------------------------
@@ -156,7 +179,7 @@ class CTCache:
     FIELDS = ("slot_state", "slot_seg", "slot_pos", "slot_bits",
               "block_type", "seg_type", "seg_level", "buf_k", "buf_v",
               "buf_len", "cur_seg", "cur_thought", "prev_thought",
-              "num_tokens")
+              "num_tokens", "ring_k", "ring_v")
 
     def __init__(self, **kw):
         for f in self.FIELDS:
@@ -196,7 +219,26 @@ def init_cache(dims: CacheDims) -> CTCache:
         cur_thought=jnp.int32(ThoughtType.REASONING),
         prev_thought=jnp.int32(ThoughtType.REASONING),
         num_tokens=jnp.int32(0),
+        ring_k=ring(dims),
+        ring_v=ring(dims),
     )
+
+
+def ring(dims: CacheDims):
+    """An empty window ring ``[LW, W, H, D]`` bf16, or ``None`` (no leaf)
+    without window layers.  Position p of window layer i is at
+    ``[i, p % W]``; the slot's ``num_tokens`` is the write position, so
+    the ring holds positions ``max(0, n - W) .. n - 1`` and needs no
+    metadata, and the pool's accounting never sees it."""
+    if not dims.LW:
+        return None
+    return jnp.zeros((dims.LW, dims.W, dims.H, dims.D), jnp.bfloat16)
+
+
+def ring_valid(dims: CacheDims, n: jax.Array) -> jax.Array:
+    """Ring entries holding a position once ``n`` positions were written
+    ``[..., W]`` (``n`` any shape)."""
+    return jnp.arange(dims.W) < jnp.minimum(n, dims.W)[..., None]
 
 
 # ---------------------------------------------------------------------------

@@ -243,7 +243,8 @@ def serve_cache_specs(cache, batched: bool):
     """CTCache pytree of PartitionSpec: TBQ buffer planes head-sharded,
     all metadata replicated.  ``batched`` selects the engine's stacked
     ``[R, ...]`` layout vs a single request's."""
-    spec = {f: P() for f in cache.FIELDS}
+    spec = {f: None if getattr(cache, f) is None else P()
+            for f in cache.FIELDS}
     spec["buf_k"] = spec["buf_v"] = serve_buf_spec(batched)
     return type(cache)(**spec)
 

@@ -13,9 +13,9 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.config import ModelConfig
+from repro.config import WINDOW, ModelConfig
 from repro.layers.common import dense_init, split_keys
-from repro.layers.rope import apply_rope, rope_freqs
+from repro.layers.rope import apply_rope, rope_freqs, rope_table
 
 NEG_INF = -1e30
 
@@ -33,11 +33,21 @@ def attn_params(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
         p["bq"] = jnp.zeros((cfg.num_heads * hd,), dtype)
         p["bk"] = jnp.zeros((cfg.num_kv_heads * hd,), dtype)
         p["bv"] = jnp.zeros((cfg.num_kv_heads * hd,), dtype)
+    if cfg.qk_norm:
+        p["q_norm"] = jnp.ones((hd,), jnp.float32)
+        p["k_norm"] = jnp.ones((hd,), jnp.float32)
     return p
 
 
+def _head_rms(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    xf = x.astype(jnp.float32)
+    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    return (xf * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
+
+
 def _project_qkv(p: dict, x: jax.Array, cfg: ModelConfig):
-    """x [..., D] -> q [..., Hq, hd], k/v [..., Hkv, hd] (pre-RoPE)."""
+    """x [..., D] -> q [..., Hq, hd], k/v [..., Hkv, hd] (pre-RoPE; with
+    ``qk_norm``, q and k RMS-normed per head)."""
     hd = cfg.head_dim
     q = x @ p["wq"]
     k = x @ p["wk"]
@@ -47,7 +57,19 @@ def _project_qkv(p: dict, x: jax.Array, cfg: ModelConfig):
     q = q.reshape(*x.shape[:-1], cfg.num_heads, hd)
     k = k.reshape(*x.shape[:-1], cfg.num_kv_heads, hd)
     v = v.reshape(*x.shape[:-1], cfg.num_kv_heads, hd)
+    if cfg.qk_norm:
+        q = _head_rms(q, p["q_norm"], cfg.norm_eps)
+        k = _head_rms(k, p["k_norm"], cfg.norm_eps)
     return q, k, v
+
+
+def qkv_rope(p: dict, x: jax.Array, cfg: ModelConfig, positions: jax.Array,
+             kind: str):
+    """Projections with the rope of layer kind ``kind``: x [T, D],
+    positions [T] -> q [T, Hq, hd], k/v [T, Hkv, hd]."""
+    q, k, v = _project_qkv(p, x, cfg)
+    cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_of(kind))
+    return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
 
 def qkv_decode(p: dict, x: jax.Array, cfg: ModelConfig,
@@ -159,15 +181,23 @@ def _full_attention(q, k, v, *, causal: bool, window: int,
 
 def attn_forward(p: dict, x: jax.Array, cfg: ModelConfig,
                  positions: jax.Array, *, causal: bool = True,
-                 kv_override: Optional[Tuple[jax.Array, jax.Array]] = None
-                 ) -> jax.Array:
+                 kv_override: Optional[Tuple[jax.Array, jax.Array]] = None,
+                 kind: Optional[str] = None) -> jax.Array:
     """Full-sequence attention for train/prefill.  x [B,S,D].
 
     ``kv_override`` supplies external (k, v) for cross-attention
-    ([B,T,Hkv,hd], already position-encoded or encoder-side).
+    ([B,T,Hkv,hd], already position-encoded or encoder-side).  ``kind``
+    (a layer of a config with ``layer_kinds``) selects that kind's rope
+    and window.
     """
     q, k, v = _project_qkv(p, x, cfg)
-    if cfg.position_embedding.value == "rope":
+    window = cfg.sliding_window
+    if kind is not None:
+        cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_of(kind))
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+        window = cfg.sliding_window if kind == WINDOW else 0
+    elif cfg.position_embedding.value == "rope":
         cos, sin = rope_freqs(positions, cfg.head_dim, cfg.rope_theta)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
@@ -197,7 +227,7 @@ def attn_forward(p: dict, x: jax.Array, cfg: ModelConfig,
             if cfg.num_heads % tp != 0 or cfg.d_model // tp < 128:
                 out = ring_attention(q, k, v, mesh)
                 return out_proj(p, out)
-    out = _full_attention(q, k, v, causal=causal, window=cfg.sliding_window)
+    out = _full_attention(q, k, v, causal=causal, window=window)
     return out_proj(p, out)
 
 

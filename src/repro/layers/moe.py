@@ -1,12 +1,18 @@
-"""Mixture-of-Experts FFN with GShard-style grouped one-hot dispatch.
+"""Mixture-of-Experts FFN: a training layer and a serving layer.
 
-Tokens are processed in groups of ``dispatch_group`` so the dispatch/combine
-einsums stay O(tokens * group * d) instead of quadratic in the sequence.
-Expert weights are stacked [E, ...] and shard over the ``model`` axis (EP);
-the dispatch einsums lower to all-to-alls under GSPMD.
+:func:`moe_apply` (training) is GShard-style grouped one-hot dispatch.
+Tokens are processed in groups of ``dispatch_group`` so the
+dispatch/combine einsums stay O(tokens * group * d) instead of quadratic
+in the sequence.  Expert weights are stacked [E, ...] and shard over the
+``model`` axis (EP); the dispatch einsums lower to all-to-alls under
+GSPMD.  Top-k routing (k=2 mixtral, k=1 llama4) with renormalized gates,
+capacity dropping, and the standard load-balancing auxiliary loss.
 
-Top-k routing (k=2 mixtral, k=1 llama4) with renormalized gates, capacity
-dropping, and the standard load-balancing auxiliary loss.
+:func:`moe_held` (serving) is dropless and holds a share of the experts
+(``MoEConfig.held``): it routes over every expert, and computes exactly
+the part of the result its own experts give, with a grouped matmul
+(``jax.lax.ragged_dot``) over the tokens sorted by expert.  No capacity,
+so a token's output does not depend on the tokens served beside it.
 """
 from __future__ import annotations
 
@@ -21,13 +27,15 @@ from repro.layers.common import act_fn, dense_init, split_keys
 
 
 def moe_params(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
+    """The router over every expert; weights of the held experts only."""
     d, ff, e = cfg.d_model, cfg.d_ff, cfg.moe.num_experts
+    n = cfg.moe.num_held
     ks = split_keys(key, 4)
     return {
         "router": dense_init(ks[0], (d, e), scale=0.02, dtype=jnp.float32),
-        "w_up": dense_init(ks[1], (e, d, ff), scale=d ** -0.5, dtype=dtype),
-        "w_gate": dense_init(ks[2], (e, d, ff), scale=d ** -0.5, dtype=dtype),
-        "w_down": dense_init(ks[3], (e, ff, d), scale=ff ** -0.5, dtype=dtype),
+        "w_up": dense_init(ks[1], (n, d, ff), scale=d ** -0.5, dtype=dtype),
+        "w_gate": dense_init(ks[2], (n, d, ff), scale=d ** -0.5, dtype=dtype),
+        "w_down": dense_init(ks[3], (n, ff, d), scale=ff ** -0.5, dtype=dtype),
     }
 
 
@@ -36,6 +44,10 @@ def moe_apply(p: dict, x: jax.Array, cfg: ModelConfig
     """x [B, S, D] -> (y [B, S, D], aux_loss scalar)."""
     mcfg = cfg.moe
     e, k = mcfg.num_experts, mcfg.num_experts_per_token
+    if mcfg.num_held != e:
+        raise NotImplementedError(
+            "moe_apply computes every expert; a device holding a share of "
+            "them serves through moe_held")
     b, s, d = x.shape
     n = b * s
     gsz = min(mcfg.dispatch_group, n)
@@ -68,10 +80,9 @@ def moe_apply(p: dict, x: jax.Array, cfg: ModelConfig
     # over `model` — otherwise GSPMD routes dispatch through all-reduces of
     # the full [g,E,C,D] tensors (§Perf llama4 iteration: 515 GB/step)
     from repro.distributed.sharding import constrain
-    import os
     # dispatch/expert compute in the model dtype (bf16), router math in f32
-    # (§Perf llama4 iteration 3); REPRO_F32_MOE restores the f32 baseline
-    cdt = jnp.float32 if os.environ.get("REPRO_F32_MOE") else x.dtype
+    # (§Perf llama4 iteration 3)
+    cdt = x.dtype
     dispatch = constrain(dispatch.astype(cdt), "dp", None, "model", None)
     combine = constrain(combine.astype(cdt), "dp", None, "model", None)
     ein = jnp.einsum
@@ -91,3 +102,61 @@ def moe_apply(p: dict, x: jax.Array, cfg: ModelConfig
     pmean = jnp.mean(probs, axis=(0, 1))
     aux = e * jnp.sum(frac * pmean)
     return y.reshape(b, s, d).astype(x.dtype), aux
+
+
+def route(router: jax.Array, x: jax.Array, cfg: ModelConfig
+          ) -> Tuple[jax.Array, jax.Array]:
+    """Softmax router over every expert, top-k, renormalised (as
+    :func:`moe_apply` gates): x [T, D] -> (gates [T, k] f32, experts
+    [T, k]).  The logits are a true float32 product (``HIGHEST``; the
+    TPU's default would round both operands to bf16): the top-k choice
+    is discrete, and that rounding alone flips near-tied experts."""
+    logits = jnp.matmul(x.astype(jnp.float32), router,
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate, idx = jax.lax.top_k(probs, cfg.moe.num_experts_per_token)
+    return gate / jnp.sum(gate, -1, keepdims=True), idx
+
+
+def moe_held(p: dict, x: jax.Array, cfg: ModelConfig,
+             valid: jax.Array | None = None
+             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Dropless expert layer over the experts this device holds.
+
+    x [T, D] -> ``(y [T, D], assigned, hit)``: ``y`` is the held
+    experts' part of the layer's output (each routed (token, expert) pair
+    on a held expert, weighted by its gate; pairs routed elsewhere add
+    nothing); ``assigned`` counts those pairs and ``hit`` the held
+    experts that received at least one (int32 scalars).  Tokens with
+    ``valid`` [T] False (padding, idle slots) route nowhere.
+
+    The T * k pairs are sorted by held expert (pairs routed elsewhere
+    last) and run through one grouped matmul per projection
+    (``ragged_dot``); each token's k rows are gathered back and summed
+    in a fixed order, so nothing depends on the other tokens."""
+    m = cfg.moe
+    k = m.num_experts_per_token
+    e0, e1 = m.held_range
+    n = e1 - e0
+    t = x.shape[0]
+    gate, idx = route(p["router"], x, cfg)                  # [T, k]
+    local = idx - e0
+    held = (local >= 0) & (local < n)
+    if valid is not None:
+        held = held & valid[:, None]
+    expert = jnp.where(held, local, n).reshape(-1)          # [T*k]
+    order = jnp.argsort(expert, stable=True)
+    sizes = jnp.sum(jax.nn.one_hot(expert, n + 1, dtype=jnp.int32),
+                    axis=0)[:n]
+    rows = x[order // k]                                    # [T*k, D]
+    f = act_fn(cfg.act)
+    h = f(jax.lax.ragged_dot(rows, p["w_gate"], sizes)) * \
+        jax.lax.ragged_dot(rows, p["w_up"], sizes)
+    ye = jax.lax.ragged_dot(h, p["w_down"], sizes)          # [T*k, D]
+    # rows past the held groups are not computed: zero them
+    ye = jnp.where((jnp.arange(t * k) < jnp.sum(sizes))[:, None], ye, 0)
+    ye = ye[jnp.argsort(order)].reshape(t, k, -1)           # token order
+    w = jnp.where(held, gate, 0.0).astype(ye.dtype)
+    y = jnp.sum(ye * w[..., None], axis=1)
+    return (y.astype(x.dtype), jnp.sum(held, dtype=jnp.int32),
+            jnp.sum(sizes > 0, dtype=jnp.int32))

@@ -50,15 +50,22 @@ def init(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
 
 
 def _block(cfg: ModelConfig, lp: dict, h: jax.Array, positions: jax.Array,
-           causal: bool = True) -> Tuple[jax.Array, jax.Array]:
-    """One decoder block over [B,S,D].  Returns (h, moe_aux)."""
+           causal: bool = True, kind: Optional[str] = None
+           ) -> Tuple[jax.Array, jax.Array]:
+    """One decoder block over [B,S,D].  Returns (h, moe_aux).  ``kind``
+    is the layer's attention kind in a config with ``layer_kinds``."""
     from repro.distributed.sharding import constrain
     h = constrain(h, "dp", None, None)   # keep batch sharded through the scan
     a = A.attn_forward(lp["attn"], rmsnorm(lp["norm1"], h, cfg.norm_eps),
-                       cfg, positions, causal=causal)
+                       cfg, positions, causal=causal, kind=kind)
     h = h + a
     x2 = rmsnorm(lp["norm2"], h, cfg.norm_eps)
-    if cfg.moe is not None:
+    if cfg.moe is not None and cfg.moe.num_held != cfg.moe.num_experts:
+        # a share of the experts: the dropless held-expert layer
+        b, s, d = x2.shape
+        m = MOE.moe_held(lp["moe"], x2.reshape(b * s, d), cfg)[0]
+        m, aux = m.reshape(b, s, d), jnp.float32(0)
+    elif cfg.moe is not None:
         m, aux = MOE.moe_apply(lp["moe"], x2, cfg)
     else:
         m, aux = mlp(lp["mlp"], x2, cfg.act, cfg.mlp_gated), jnp.float32(0)
@@ -68,16 +75,28 @@ def _block(cfg: ModelConfig, lp: dict, h: jax.Array, positions: jax.Array,
 def backbone(params: dict, h: jax.Array, cfg: ModelConfig,
              positions: jax.Array, *, remat: bool = False,
              causal: bool = True) -> Tuple[jax.Array, jax.Array]:
-    """Scan the stacked layers over hidden states [B,S,D]."""
+    """Scan the stacked layers over hidden states [B,S,D]; a config with
+    ``layer_kinds`` runs its layers one by one, each with its kind."""
 
-    def body(carry, lp):
+    def body(carry, lp, kind=None):
         h, aux = carry
-        h, a = _block(cfg, lp, h, positions, causal)
+        h, a = _block(cfg, lp, h, positions, causal, kind)
         return (h, aux + a), None
 
-    if remat:
-        body = jax.checkpoint(body, prevent_cse=False)
-    (h, aux), _ = jax.lax.scan(body, (h, jnp.float32(0)), params["layers"])
+    if cfg.layer_kinds:
+        carry = (h, jnp.float32(0))
+        for i, kind in enumerate(cfg.layer_kinds):
+            step = functools.partial(body, kind=kind)
+            if remat:
+                step = jax.checkpoint(step, prevent_cse=False)
+            lp = jax.tree.map(lambda x: x[i], params["layers"])
+            carry, _ = step(carry, lp)
+        h, aux = carry
+    else:
+        if remat:
+            body = jax.checkpoint(body, prevent_cse=False)
+        (h, aux), _ = jax.lax.scan(body, (h, jnp.float32(0)),
+                                   params["layers"])
     return rmsnorm(params["final_norm"], h, cfg.norm_eps), aux
 
 
@@ -127,8 +146,16 @@ def loss_fn(params: dict, batch: Dict[str, jax.Array], cfg: ModelConfig,
 # FullKV serving paths (baseline; the ThinKV path lives in serving/engine.py)
 # ---------------------------------------------------------------------------
 
+def _uniform_layers_only(cfg: ModelConfig) -> None:
+    if cfg.layer_kinds:
+        raise NotImplementedError(
+            "the FullKV baseline paths serve one attention kind; a config "
+            "with layer_kinds is served by serving.engine.ThinKVEngine")
+
+
 def prefill(params: dict, batch: Dict[str, jax.Array], cfg: ModelConfig):
     """Returns (logits_last [B,V], k_cache, v_cache [L,B,S,Hkv,hd])."""
+    _uniform_layers_only(cfg)
     h, positions = assemble_inputs(params, batch, cfg)
 
     def body(h, lp):
@@ -155,6 +182,7 @@ def decode_step_fullkv(params: dict, token: jax.Array, pos: jax.Array,
 
     token []; k_cache/v_cache [L,T,Hkv,hd]; returns (logits [V], caches).
     """
+    _uniform_layers_only(cfg)
     h = E.embed(params["embed"], token[None], cfg)[0]
 
     def body(carry, inp):

@@ -43,6 +43,17 @@ oracle validates the KERNEL against dense math — not the tick against
 the sequential model.  Attention sparsity for calibrated layers is
 measured by the dense path only on ticks where some slot refreshes.
 
+WINDOW LAYERS AND HELD EXPERTS.  A model with ``layer_kinds`` keeps its
+full layers in the paged pool (``dims.L`` is their count) and its window
+layers in per-slot bf16 rings (``CTCache.ring_k/ring_v``; see
+``core.ct_cache.ring`` and ``docs/serving.md``).  Such a model's trunk and
+prefill run layer by layer (each kind's rope; window layers write their
+ring), and the tick's attention adds the XLA window attention over the
+rings beside the fused kernel over the pool.  Every MoE model's tick and
+prefill use the dropless held-expert layer (``layers.moe.moe_held``).  A
+model without kinds and experts compiles exactly the programs it
+compiled before.
+
 Prompts do not trickle one token per tick: admission runs a CHUNKED
 BATCHED PREFILL.  Prompts >= ``prefill_chunk`` (128-multiple) tokens go
 through LARGE chunks whose causal intra-chunk partition runs the COMPILED
@@ -205,7 +216,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.config import ArchFamily, ServeConfig
+from repro.config import FULL, WINDOW, ArchFamily, ServeConfig
 from repro.core import ct_cache as CC
 from repro.core.thoughts import row_sparsity
 from repro.kernels import ops as K
@@ -214,9 +225,8 @@ from repro.layers import attention as A
 from repro.layers import embedding as E
 from repro.layers.common import softcap
 from repro.layers.mlp import mlp
-from repro.layers.moe import moe_apply
+from repro.layers.moe import moe_held
 from repro.layers.norms import rmsnorm
-from repro.layers.rope import apply_rope, rope_freqs
 from repro.serving import sampling as SMP
 from repro.serving import tracing as TR
 from repro.serving.scheduler import Request, Scheduler
@@ -227,6 +237,35 @@ NEG_INF = -1e30
 # next multiple so the number of distinct compiled shapes (and hence
 # probe retraces) is bounded by max_len / DRIFT_PAD, not by request count
 DRIFT_PAD = 32
+
+
+def _check_serveable(m, mesh, lstar) -> None:
+    """Refuse, loudly, what the engine would otherwise serve as another
+    model: a window without layer kinds (it would be full attention), a
+    per-kind rope without kinds, window layers under the KV-head mesh
+    (their rings are not sharded with the pool), a model with no full
+    layer (nothing for the ThinKV pool), and L* outside the full
+    layers."""
+    if m.sliding_window > 0 and not m.layer_kinds:
+        raise ValueError(
+            f"{m.name}: sliding_window={m.sliding_window} without "
+            f"layer_kinds; the engine serves a window only in window "
+            f"layers, never as full attention")
+    if m.rope_full and not m.layer_kinds:
+        raise ValueError(f"{m.name}: a rope per layer kind needs "
+                         f"layer_kinds")
+    if not m.layer_kinds:
+        return
+    if not m.full_layers:
+        raise ValueError(f"{m.name}: no full attention layer; the ThinKV "
+                         f"pool needs at least one")
+    if m.window_layers and mesh is not None:
+        raise NotImplementedError(
+            f"{m.name}: window layers under the KV-head mesh; their rings "
+            f"are not sharded with the pool")
+    if lstar is not None and not set(lstar) <= set(m.full_layers):
+        raise ValueError(f"L* {tuple(lstar)} must be full attention "
+                         f"layers {m.full_layers}")
 
 
 def _sample_slots(slot_rngs, logits, temperature: float, top_p: float):
@@ -265,6 +304,46 @@ def _joint_attend(q, k_pool, v_pool, valid_pool, buf_k, buf_v, buf_mask):
     out = jnp.einsum("thgn,nhd->thgd", p,
                      v.astype(jnp.float32)).reshape(t, hq, hd)
     return out.astype(q.dtype), p, valid
+
+
+def _ring_attend(q, ring_k, ring_v, valid):
+    """Window layers' decode attention over the slots' rings, in XLA.
+
+    q [Lw, R, Hq, D]; rings [R, Lw, W, H, D] bf16; valid [R, W].  The
+    products take bf16 operands and accumulate in f32 (what a float32
+    product does on the TPU at default precision), so the rings are read
+    as stored.  Returns [Lw, R, Hq, D] f32."""
+    lw, r, hq, d = q.shape
+    h = ring_k.shape[3]
+    qh = q.reshape(lw, r, h, hq // h, d).astype(ring_k.dtype)
+    s = jnp.einsum("lrhgd,rlwhd->lrhgw", qh, ring_k,
+                   preferred_element_type=jnp.float32) / np.sqrt(d)
+    s = jnp.where(valid[None, :, None, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("lrhgw,rlwhd->lrhgd", p.astype(ring_v.dtype), ring_v,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(lw, r, hq, d)
+
+
+def _window_chunk_attend(q, k, v, q_pos, k_pos, k_valid, window: int):
+    """A prefill chunk's window attention: q [T, Hq, D] at positions
+    ``q_pos`` [T] over keys k/v [N, H, D] at ``k_pos`` [N] (the ring,
+    then the chunk itself), each query seeing the valid keys at
+    ``q_pos - window < k_pos <= q_pos``.  Same precision as
+    :func:`_ring_attend`; returns [T, Hq, D] f32."""
+    t, hq, d = q.shape
+    h = k.shape[1]
+    qh = q.reshape(t, h, hq // h, d).astype(jnp.bfloat16)
+    s = jnp.einsum("thgd,nhd->thgn", qh, k.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32) / np.sqrt(d)
+    seen = k_valid[None, :] & (k_pos[None, :] <= q_pos[:, None]) & \
+        (k_pos[None, :] > q_pos[:, None] - window)
+    s = jnp.where(seen[:, None, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("thgn,nhd->thgd", p.astype(jnp.bfloat16),
+                   v.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32)
+    return o.reshape(t, hq, d)
 
 
 def _probs_sparsity(p_t, valid_t, axis_name=None):
@@ -341,7 +420,10 @@ class ResultTokens:
     Wraps the device arrays one fused decode tick produced — next tokens
     [R], per-slot validity [R], generated-so-far lengths [R], last-token
     logits [R, V], plus the deferred commit-failure flag, COW-fault count
-    and the count of mapped pages its attention read — and starts their
+    and the tick's device counters (``{name: int32 scalar}``: the mapped
+    pages its attention read, and where the model has them the window
+    positions it attended and the routed pairs its held experts took,
+    see :meth:`ThinKVEngine.consume`) — and starts their
     D2H copies IMMEDIATELY at construction, so the transfer overlaps
     whatever the host dispatches next (the next tick, a prefill chunk).
     Nothing blocks until :meth:`block` (or the ``*_host`` properties),
@@ -352,7 +434,7 @@ class ResultTokens:
 
     def __init__(self, tick: int, tokens, valid: np.ndarray,
                  lengths: np.ndarray, logits, alloc_fail, cow_faults,
-                 attn_pages):
+                 counters):
         self.tick = tick                 # 1-based tick index of this result
         self.valid = valid               # [R] bool (host — scheduler truth)
         self.lengths = lengths           # [R] tokens generated AFTER this
@@ -360,9 +442,10 @@ class ResultTokens:
         self._logits = logits            # [R, V] (device)
         self._alloc_fail = alloc_fail
         self._cow_faults = cow_faults
-        self._attn_pages = attn_pages    # int32 scalar (device)
+        self._counters = counters        # {name: int32 scalar} (device)
         self._host = None
-        for x in (tokens, logits, alloc_fail, cow_faults, attn_pages):
+        for x in (tokens, logits, alloc_fail, cow_faults,
+                  *counters.values()):
             if hasattr(x, "copy_to_host_async"):
                 x.copy_to_host_async()
 
@@ -373,10 +456,12 @@ class ResultTokens:
                 tokens = np.asarray(self._tokens)
                 fail = bool(np.any(np.asarray(self._alloc_fail)))
                 cow = np.asarray(self._cow_faults).astype(np.int64)
-                pages = int(np.asarray(self._attn_pages))
+                counters = {k: int(np.asarray(v))
+                            for k, v in self._counters.items()}
             with TR.span(TR.FETCH_LOGITS):
                 logits = np.asarray(self._logits)
-            self._host = (tokens, logits, fail, int(cow.sum()), cow, pages)
+            self._host = (tokens, logits, fail, int(cow.sum()), cow,
+                          counters)
         return self
 
     @property
@@ -402,9 +487,10 @@ class ResultTokens:
         return self.block()._host[4]
 
     @property
-    def attn_pages_host(self) -> int:
-        """Mapped pool pages the tick's attention read through its
-        tables (the pages the fused kernel fetches)."""
+    def counters_host(self) -> Dict[str, int]:
+        """The tick's device counters (``attn_pages``: mapped pool pages
+        its attention read through the tables, the pages the fused
+        kernel fetches; see :meth:`ThinKVEngine.consume`)."""
         return self.block()._host[5]
 
 
@@ -417,7 +503,7 @@ class MultiResultTokens:
     ``[N, R]`` (a slot that finished via EOS/length inside the pack is
     invalid from the NEXT trip on), per-trip logits ``[N, R, V]``, the
     per-slot COW-fault counts, the OR'd allocation-failure flag, the
-    mapped pages its trips' attention read, and the trip count the loop
+    device counters summed over its trips, and the trip count the loop
     actually executed (``trips_host < requested``
     means a scheduling event — a slot finishing — exited the loop
     early).  Rows ``trips_host..N-1`` of every buffer are zero-filled
@@ -435,7 +521,7 @@ class MultiResultTokens:
     packed = True
 
     def __init__(self, base_tick: int, requested: int, tokens, valid,
-                 logits, alloc_fail, cow_faults, trips, attn_pages):
+                 logits, alloc_fail, cow_faults, trips, counters):
         self.base_tick = base_tick       # metrics["ticks"] at dispatch
         self.tick = base_tick + 1        # first fused tick (dispatch log)
         self.requested = requested       # host-precomputed safe trip cap
@@ -445,10 +531,10 @@ class MultiResultTokens:
         self._alloc_fail = alloc_fail
         self._cow_faults = cow_faults    # [R] per-slot (device)
         self._trips = trips              # int32 scalar (device)
-        self._attn_pages = attn_pages    # int32 scalar, summed over trips
+        self._counters = counters        # {name: int32}, summed over trips
         self._host = None
         for x in (tokens, valid, logits, alloc_fail, cow_faults, trips,
-                  attn_pages):
+                  *counters.values()):
             if hasattr(x, "copy_to_host_async"):
                 x.copy_to_host_async()
 
@@ -461,10 +547,11 @@ class MultiResultTokens:
                 fail = bool(np.any(np.asarray(self._alloc_fail)))
                 cow = np.asarray(self._cow_faults).astype(np.int64)
                 trips = int(np.asarray(self._trips))
-                pages = int(np.asarray(self._attn_pages))
+                counters = {k: int(np.asarray(v))
+                            for k, v in self._counters.items()}
             with TR.span(TR.FETCH_LOGITS):
                 logits = np.asarray(self._logits)
-            self._host = (tokens, valid, logits, fail, cow, trips, pages)
+            self._host = (tokens, valid, logits, fail, cow, trips, counters)
         return self
 
     @property
@@ -496,7 +583,7 @@ class MultiResultTokens:
         return self.block()._host[5]
 
     @property
-    def attn_pages_host(self) -> int:
+    def counters_host(self) -> Dict[str, int]:
         return self.block()._host[6]
 
 
@@ -546,12 +633,22 @@ class ThinKVEngine:
         from repro.core.policy import get_policy
         self.policy = get_policy(policy)
         self.policy.validate(cfg.thinkv)
+        _check_serveable(cfg.model, mesh, lstar)
         from repro.models import build_model
         self.model = build_model(cfg.model)
         self.params = params if params is not None \
             else self.model.init_params(cfg.seed)
-        self.dims = CC.make_dims(self.tk, cfg.model.num_layers,
-                                 cfg.model.num_kv_heads, cfg.model.head_dim)
+        # layer kinds: full layers keep their keys and values in the paged
+        # pool, window layers in per-slot rings (``ct_cache.layer_map``);
+        # without kinds every layer is full and nothing below changes
+        full = cfg.model.full_layers
+        self._kinds = cfg.model.layer_kinds or None
+        self._layer_index = CC.layer_map(self._kinds) if self._kinds \
+            else None
+        self.dims = CC.make_dims(self.tk, len(full),
+                                 cfg.model.num_kv_heads, cfg.model.head_dim,
+                                 window_layers=len(cfg.model.window_layers),
+                                 window=cfg.model.sliding_window)
         # --- tensor-parallel sharding over the KV-head axis (see module
         # docstring): pool planes / TBQ buffers / attention sharded over
         # mesh["model"], everything head-agnostic replicated ---
@@ -566,9 +663,10 @@ class ThinKVEngine:
             self._nshard, self._axis = n, SH.SERVE_HEAD_AXIS
         else:
             self._nshard, self._axis = 1, None
-        n_lstar = min(self.tk.num_calib_layers, cfg.model.num_layers)
-        self.lstar = tuple(int(x) for x in (
-            lstar if lstar is not None else range(n_lstar)))
+        # L* (the sparsity probe's layers) as pool layers: full layers only
+        n_lstar = min(self.tk.num_calib_layers, len(full))
+        self.lstar = tuple(full.index(int(x)) for x in (
+            lstar if lstar is not None else full[:n_lstar]))
         self.scheduler = Scheduler(cfg.max_seqs)
         self.num_pool_blocks = pool_blocks if pool_blocks is not None \
             else cfg.max_seqs * self.dims.NB
@@ -652,6 +750,9 @@ class ThinKVEngine:
                                           "host_syncs": 0,
                                           "attn_pages": 0,
                                           "attn_page_slots": 0}
+        self.metrics.update(dict.fromkeys(self._counter_names(), 0))
+        if cfg.model.moe is not None:
+            self.metrics["expert_slots"] = 0
         from repro.serving.prefix_cache import PrefixCache
         self.prefix_cache = PrefixCache(
             self.dims, capacity=prefix_cache_capacity) \
@@ -777,6 +878,10 @@ class ThinKVEngine:
         ax = self._axis                      # None off-mesh
         H_loc = dims.H // self._nshard       # kv heads per shard
         Hq_loc = cfg.num_heads // self._nshard
+        full_idx = np.asarray(cfg.full_layers)
+        win_idx = np.asarray(cfg.window_layers)
+        # model layer -> its row of concat([pool layers, window layers])
+        by_kind = np.argsort(np.concatenate([full_idx, win_idx]))
 
         def tick_core(params, pool, tables, caches, tokens, active):
             # every phase runs under a named scope (``serving.tracing``),
@@ -790,6 +895,7 @@ class ThinKVEngine:
             # slots whose refresh fires in THIS tick's engine_advance
             refresh_due = active & \
                 ((caches.num_tokens + 1) % tk.refresh_interval == 0)
+            counters = {}
 
             # ---- pass 1: qkv projections + buffer write + MLP trunk ----
             def trunk(carry, inp):
@@ -810,27 +916,66 @@ class ThinKVEngine:
                 buf_v = jax.vmap(upd)(buf_v, self._local_heads(v, 1),
                                       buf_len)
                 x2 = rmsnorm(lp["norm2"], h, cfg.norm_eps)
-                if cfg.moe is not None:
-                    m, _ = moe_apply(lp["moe"], x2[:, None], cfg)
-                    m = m[:, 0]
-                else:
-                    m = mlp(lp["mlp"], x2, cfg.act, cfg.mlp_gated)
-                return (h + m, buf_k, buf_v), q
+                m, routed = self._ffn(lp, x2, active)
+                return (h + m, buf_k, buf_v), (q, routed)
+
+            def trunk_by_kind(h, bufs):
+                """The trunk of a model with layer kinds, layer by layer:
+                a window layer writes its key and value into the slot's
+                ring at ``pos % W``, a full layer into the TBQ buffer;
+                each applies its kind's rope."""
+                buf_k, buf_v, ring_k, ring_v = bufs
+                rows = jnp.arange(R)
+                qs, routed = [], []
+                for l, kind in enumerate(self._kinds):
+                    lp = jax.tree.map(lambda x: x[l], params["layers"])
+                    i = self._layer_index[l]
+                    x1 = rmsnorm(lp["norm1"], h, cfg.norm_eps)
+                    q, k, v = A.qkv_rope(lp["attn"], x1, cfg, pos, kind)
+                    if kind == WINDOW:
+                        at = pos % dims.W
+                        ring_k = ring_k.at[rows, i, at].set(
+                            k.astype(ring_k.dtype))
+                        ring_v = ring_v.at[rows, i, at].set(
+                            v.astype(ring_v.dtype))
+                    else:
+                        buf_k = buf_k.at[rows, i, buf_len].set(
+                            k.astype(buf_k.dtype))
+                        buf_v = buf_v.at[rows, i, buf_len].set(
+                            v.astype(buf_v.dtype))
+                    m, r = self._ffn(
+                        lp, rmsnorm(lp["norm2"], h, cfg.norm_eps), active)
+                    h = h + m
+                    qs.append(q)
+                    routed.append(r)
+                return h, (buf_k, buf_v, ring_k, ring_v), jnp.stack(qs), \
+                    jax.tree.map(lambda *a: jnp.stack(a), *routed)
 
             with TR.scope(TR.TRUNK):
                 h = jax.vmap(lambda t: E.embed(params["embed"], t[None],
                                                cfg)[0])(tokens)  # [R, Dm]
-                (h, buf_k, buf_v), qs = jax.lax.scan(
-                    trunk, (h, caches.buf_k, caches.buf_v),
-                    (jnp.arange(cfg.num_layers), params["layers"]))
+                if self._kinds is None:
+                    (h, buf_k, buf_v), (qs, routed) = jax.lax.scan(
+                        trunk, (h, caches.buf_k, caches.buf_v),
+                        (jnp.arange(cfg.num_layers), params["layers"]))
+                else:
+                    h, (buf_k, buf_v, ring_k, ring_v), qs, routed = \
+                        trunk_by_kind(h, (caches.buf_k, caches.buf_v,
+                                          caches.ring_k, caches.ring_v))
+                    caches = caches.replace(ring_k=ring_k, ring_v=ring_v)
             caches = caches.replace(buf_k=buf_k, buf_v=buf_v)
+            if cfg.moe is not None:
+                counters["expert_assignments"] = jnp.sum(routed[0])
+                counters["experts_hit"] = jnp.sum(routed[1])
             n_buf = buf_len + 1                                  # [R]
             # mapped pool pages the attention reads: the fused kernel
             # fetches these and skips the unmapped (-1) table entries
-            attn_pages = jnp.sum(tables >= 0, dtype=jnp.int32)
+            counters["attn_pages"] = jnp.sum(tables >= 0, dtype=jnp.int32)
+            # the pool layers' queries (all layers without kinds)
+            qs_pool = qs if self._kinds is None else qs[full_idx]
             # queries of this shard's kv heads ([L, R, Hq/N, hd]; the Hq
             # axis is kv-head-major, so the slice is contiguous)
-            qs_loc = self._local_heads(qs, 2)
+            qs_loc = self._local_heads(qs_pool, 2)
 
             def dense_one_layer(kc_l, vc_l, ks_l, vs_l, q_l, st_l, bt_l,
                                 tb_l, bk_l, bv_l):
@@ -856,11 +1001,10 @@ class ThinKVEngine:
                     qs_loc[l], caches.slot_state[:, l],
                     caches.slot_bits[:, l],
                     tables[:, l], buf_k[:, l], buf_v[:, l])
-
             # ---- pass 2: attention, ONCE, over the stacked queries ----
             if backend == "kernel":
                 with TR.scope(TR.ATTENTION):
-                    qh = qs_loc.reshape(cfg.num_layers, R, H_loc, gq,
+                    qh = qs_loc.reshape(dims.L, R, H_loc, gq,
                                         cfg.head_dim).astype(jnp.float32)
                     o_all = K.paged_decode_attention_fused(
                         qh, pool.view.k_codes, pool.view.v_codes,
@@ -869,7 +1013,7 @@ class ThinKVEngine:
                         CC.stacked_slot_plane(dims, caches.slot_bits),
                         tables, CC.stacked_buffers(buf_k),
                         CC.stacked_buffers(buf_v), n_buf, force=self._force)
-                    o_all = o_all.reshape(cfg.num_layers, R, Hq_loc,
+                    o_all = o_all.reshape(dims.L, R, Hq_loc,
                                           cfg.head_dim).astype(qs.dtype)
                 # sparsity is only CONSUMED at tau refresh boundaries — run
                 # the dense probs pass for the calibrated layers only on
@@ -900,6 +1044,19 @@ class ThinKVEngine:
                          jnp.swapaxes(buf_k, 0, 1),
                          jnp.swapaxes(buf_v, 0, 1)))
                     sparsity = jnp.mean(spars_all[lstar_arr], axis=0)
+
+            if dims.LW:
+                # window layers: every slot's ring, masked to the
+                # positions written (the current token's included)
+                n_ring = jnp.minimum(pos + 1, dims.W)
+                with TR.scope(TR.WINDOW_ATTENTION):
+                    o_win = _ring_attend(qs[win_idx], caches.ring_k,
+                                         caches.ring_v,
+                                         CC.ring_valid(dims, pos + 1))
+                counters["window_positions"] = dims.LW * jnp.sum(
+                    jnp.where(active, n_ring, 0), dtype=jnp.int32)
+                o_all = jnp.concatenate(
+                    [o_all, o_win.astype(o_all.dtype)])[by_kind]
 
             # ---- pass 3: attention output residuals ----
             def residual(hc, inp):
@@ -936,7 +1093,7 @@ class ThinKVEngine:
                 logits = softcap(E.unembed(params["embed"], h, cfg),
                                  cfg.logit_softcap)              # [R, V]
             return (pool, tables_out, caches, sparsity, logits,
-                    alloc_fail, cow_faults, attn_pages)
+                    alloc_fail, cow_faults, counters)
 
         return tick_core
 
@@ -951,13 +1108,13 @@ class ThinKVEngine:
 
         def tick(params, pool, tables, caches, tokens, active, slot_rngs):
             (pool, tables_out, caches, sparsity, logits, alloc_fail,
-             cow_faults, attn_pages) = core(params, pool, tables, caches,
-                                            tokens, active)
+             cow_faults, counters) = core(params, pool, tables, caches,
+                                          tokens, active)
             with TR.scope(TR.SAMPLE):
                 nxt, slot_rngs = _sample_slots(slot_rngs, logits, temp,
                                                top_p)
             return (nxt, pool, tables_out, caches, sparsity, logits,
-                    alloc_fail, cow_faults, slot_rngs, attn_pages)
+                    alloc_fail, cow_faults, slot_rngs, counters)
 
         pool_s, cache_s, rep = self._spmd_specs(single_request=False)
         return self._wrap_spmd(
@@ -1005,10 +1162,10 @@ class ThinKVEngine:
             def body(c):
                 (t, pool, tables, caches, tokens, active, slot_rngs,
                  produced, toks, valid, logits_buf, fail, _stop, cow,
-                 pages) = c
+                 counters) = c
                 (pool, tables, caches, _, logits, fail_t, cow_t,
-                 pages_t) = core(params, pool, tables, caches, tokens,
-                                 active)
+                 counters_t) = core(params, pool, tables, caches, tokens,
+                                    active)
                 with TR.scope(TR.SAMPLE):
                     nxt, slot_rngs = _sample_slots(slot_rngs, logits, temp,
                                                    top_p)
@@ -1021,7 +1178,8 @@ class ThinKVEngine:
                 return (t + 1, pool, tables, caches, nxt, active & ~done,
                         slot_rngs, produced, toks, valid, logits_buf,
                         fail | jnp.any(fail_t), jnp.any(done),
-                        cow + cow_t.astype(jnp.int32), pages + pages_t)
+                        cow + cow_t.astype(jnp.int32),
+                        jax.tree.map(jnp.add, counters, counters_t))
 
             init = (jnp.int32(0), pool, tables, caches, tokens, active,
                     slot_rngs, jnp.zeros(R, jnp.int32),
@@ -1029,12 +1187,13 @@ class ThinKVEngine:
                     jnp.zeros((N, R), bool),
                     jnp.zeros((N, R, V), jnp.float32),
                     jnp.bool_(False), jnp.bool_(False),
-                    jnp.zeros(R, jnp.int32), jnp.int32(0))
+                    jnp.zeros(R, jnp.int32),
+                    {k: jnp.int32(0) for k in self._counter_names()})
             (t, pool, tables, caches, _, _, slot_rngs, _, toks, valid,
-             logits_buf, fail, _, cow, pages) = jax.lax.while_loop(
+             logits_buf, fail, _, cow, counters) = jax.lax.while_loop(
                  cond, body, init)
             return (toks, valid, logits_buf, pool, tables, caches,
-                    slot_rngs, t, fail, cow, pages)
+                    slot_rngs, t, fail, cow, counters)
 
         pool_s, cache_s, rep = self._spmd_specs(single_request=False)
         return self._wrap_spmd(
@@ -1043,6 +1202,16 @@ class ThinKVEngine:
                       rep),
             out_specs=(rep, rep, rep, pool_s, rep, cache_s, rep, rep, rep,
                        rep, rep))
+
+    def _counter_names(self) -> tuple:
+        """The tick's device counters (:meth:`consume` folds each into
+        ``metrics``)."""
+        names = ("attn_pages",)
+        if self.dims.LW:
+            names += ("window_positions",)
+        if self.mcfg.moe is not None:
+            names += ("expert_assignments", "experts_hit")
+        return names
 
     # ------------------------------------------------------------------
     def _make_prefill_chunk(self):
@@ -1061,16 +1230,18 @@ class ThinKVEngine:
             refresh_due = ((start + n_valid) % tk.refresh_interval) == 0
             h = E.embed(params["embed"], tokens_c, cfg)          # [C, Dm]
 
-            def body(carry, inp):
+            def body(carry, inp, kind=None):
+                """One full layer (``lidx`` its pool layer; ``kind`` set
+                in a model with layer kinds)."""
                 h, buf_k, buf_v = carry
                 lidx, lp, kc_l, vc_l, ks_l, vs_l = inp
                 x1 = rmsnorm(lp["norm1"], h, cfg.norm_eps)
-                q, k, v = A._project_qkv(lp["attn"], x1, cfg)    # [C,*,hd]
-                if cfg.position_embedding.value == "rope":
-                    cos, sin = rope_freqs(positions, cfg.head_dim,
-                                          cfg.rope_theta)
-                    q = apply_rope(q, cos, sin)
-                    k = apply_rope(k, cos, sin)
+                if kind is not None or \
+                        cfg.position_embedding.value == "rope":
+                    q, k, v = A.qkv_rope(lp["attn"], x1, cfg, positions,
+                                         kind or FULL)
+                else:
+                    q, k, v = A._project_qkv(lp["attn"], x1, cfg)
                 km = jnp.where(tok_valid[:, None, None],
                                k, 0.0).astype(buf_k.dtype)
                 vm = jnp.where(tok_valid[:, None, None],
@@ -1113,20 +1284,35 @@ class ThinKVEngine:
 
                 h = h + A.out_proj(lp["attn"], self._gather_heads(o, 1))
                 x2 = rmsnorm(lp["norm2"], h, cfg.norm_eps)
-                if cfg.moe is not None:
-                    m, _ = moe_apply(lp["moe"], x2[None], cfg)
-                    m = m[0]
-                else:
-                    m = mlp(lp["mlp"], x2, cfg.act, cfg.mlp_gated)
+                m = self._ffn(lp, x2, tok_valid)[0]
                 return (h + m, buf_k, buf_v), spars
 
-            (h, buf_k, buf_v), spars_all = jax.lax.scan(
-                body, (h, cache.buf_k, cache.buf_v),
-                (jnp.arange(cfg.num_layers), params["layers"],
-                 pool.view.k_codes, pool.view.v_codes,
-                 pool.view.k_scales, pool.view.v_scales))
-            cache = cache.replace(buf_k=buf_k, buf_v=buf_v)
-            sparsity = jnp.mean(spars_all[lstar])
+            if self._kinds is None:
+                (h, buf_k, buf_v), spars_all = jax.lax.scan(
+                    body, (h, cache.buf_k, cache.buf_v),
+                    (jnp.arange(cfg.num_layers), params["layers"],
+                     pool.view.k_codes, pool.view.v_codes,
+                     pool.view.k_scales, pool.view.v_scales))
+                cache = cache.replace(buf_k=buf_k, buf_v=buf_v)
+                sparsity = jnp.mean(spars_all[lstar])
+            else:
+                bufs, ring = (cache.buf_k, cache.buf_v), \
+                    (cache.ring_k, cache.ring_v)
+                spars_all = []
+                for l, kind in enumerate(self._kinds):
+                    lp = jax.tree.map(lambda x: x[l], params["layers"])
+                    i = self._layer_index[l]
+                    if kind == WINDOW:
+                        h, ring = self._window_layer(h, ring, lp, i, start,
+                                                     n_valid, tok_valid)
+                        continue
+                    (h, *bufs), sp = body(
+                        (h, *bufs), (i, lp, *(p[i] for p in pool.view)),
+                        kind)
+                    spars_all.append(sp)
+                cache = cache.replace(buf_k=bufs[0], buf_v=bufs[1],
+                                      ring_k=ring[0], ring_v=ring[1])
+                sparsity = jnp.mean(jnp.stack(spars_all)[lstar])
 
             pool, table, cache, fail, n_cow = CC.engine_advance(
                 tk, dims, pool, table, cache, sparsity,
@@ -1145,6 +1331,49 @@ class ThinKVEngine:
             chunk_step,
             in_specs=(rep, pool_s, rep, cache_s, rep, rep),
             out_specs=(pool_s, rep, cache_s, rep, rep, rep))
+
+    def _ffn(self, lp, x2, valid):
+        """A layer's MLP, or its held experts (dropless; tokens with
+        ``valid`` False, idle slots or prefill padding, route nowhere):
+        ``(residual, routed)``, ``routed`` the held experts' (assigned
+        pairs, experts hit), or ``()`` for an MLP."""
+        cfg = self.mcfg
+        if cfg.moe is None:
+            return mlp(lp["mlp"], x2, cfg.act, cfg.mlp_gated), ()
+        with TR.scope(TR.MOE):
+            m, assigned, hit = moe_held(lp["moe"], x2, cfg, valid=valid)
+        return m, (assigned, hit)
+
+    def _window_layer(self, h, ring, lp, i, start, n_valid, valid):
+        """Window layer ``i`` over a prefill chunk ``h`` [C, D] (positions
+        ``start + t``, the first ``n_valid`` real; ``valid`` their mask,
+        None for a full chunk): its queries attend the ring, which holds
+        the positions before ``start``, and the chunk itself, each
+        within the window; then the chunk's last ``min(n_valid, W)`` keys
+        and values go into the ring.  Returns ``(h, (ring_k, ring_v))``."""
+        cfg, W = self.mcfg, self.dims.W
+        ring_k, ring_v = ring
+        t = jnp.arange(h.shape[0])
+        q_pos = start + t
+        x1 = rmsnorm(lp["norm1"], h, cfg.norm_eps)
+        q, k, v = A.qkv_rope(lp["attn"], x1, cfg, q_pos, WINDOW)
+        j = jnp.arange(W)
+        # ring entry j holds the last position before start that is j
+        # modulo W (negative: none yet)
+        ring_pos = j + W * ((start - 1 - j) // W)
+        o = _window_chunk_attend(
+            q, jnp.concatenate([ring_k[i], k.astype(ring_k.dtype)]),
+            jnp.concatenate([ring_v[i], v.astype(ring_v.dtype)]),
+            q_pos, jnp.concatenate([ring_pos, q_pos]),
+            jnp.concatenate([ring_pos >= 0, t < n_valid]), W)
+        # only the last W real tokens land (earlier ones would collide)
+        keep = (t < n_valid) & (t >= n_valid - W)
+        at = jnp.where(keep, q_pos % W, W)
+        ring_k = ring_k.at[i, at].set(k.astype(ring_k.dtype), mode="drop")
+        ring_v = ring_v.at[i, at].set(v.astype(ring_v.dtype), mode="drop")
+        h = h + A.out_proj(lp["attn"], o.astype(h.dtype))
+        m = self._ffn(lp, rmsnorm(lp["norm2"], h, cfg.norm_eps), valid)[0]
+        return h + m, (ring_k, ring_v)
 
     def _chunk_kernel(self, q, kc_l, vc_l, ks_l, vs_l, state_l, bits_l,
                       table_l, k_chunk, v_chunk, tok_valid):
@@ -1206,16 +1435,18 @@ class ThinKVEngine:
                 (start + jnp.arange(1, C + 1)) % tk.refresh_interval == 0)
             h = E.embed(params["embed"], tokens_c, cfg)          # [C, Dm]
 
-            def body(carry, inp):
+            def body(carry, inp, kind=None):
+                """One full layer (``lidx`` its pool layer; ``kind`` set
+                in a model with layer kinds)."""
                 h = carry
                 lidx, lp, kc_l, vc_l, ks_l, vs_l = inp
                 x1 = rmsnorm(lp["norm1"], h, cfg.norm_eps)
-                q, k, v = A._project_qkv(lp["attn"], x1, cfg)    # [C,*,hd]
-                if cfg.position_embedding.value == "rope":
-                    cos, sin = rope_freqs(positions, cfg.head_dim,
-                                          cfg.rope_theta)
-                    q = apply_rope(q, cos, sin)
-                    k = apply_rope(k, cos, sin)
+                if kind is not None or \
+                        cfg.position_embedding.value == "rope":
+                    q, k, v = A.qkv_rope(lp["attn"], x1, cfg, positions,
+                                         kind or FULL)
+                else:
+                    q, k, v = A._project_qkv(lp["attn"], x1, cfg)
                 state_l = cache.slot_state[lidx]                 # [NS]
                 bits_l = cache.slot_bits[lidx]
                 table_l = table[lidx]                            # [NB]
@@ -1245,18 +1476,30 @@ class ThinKVEngine:
 
                 h = h + A.out_proj(lp["attn"], self._gather_heads(o, 1))
                 x2 = rmsnorm(lp["norm2"], h, cfg.norm_eps)
-                if cfg.moe is not None:
-                    m, _ = moe_apply(lp["moe"], x2[None], cfg)
-                    m = m[0]
-                else:
-                    m = mlp(lp["mlp"], x2, cfg.act, cfg.mlp_gated)
+                m = self._ffn(lp, x2, None)[0]
                 return h + m, (spars, k, v)
 
-            h, (spars_all, ks_all, vs_all) = jax.lax.scan(
-                body, h,
-                (jnp.arange(cfg.num_layers), params["layers"],
-                 pool.view.k_codes, pool.view.v_codes,
-                 pool.view.k_scales, pool.view.v_scales))
+            if self._kinds is None:
+                h, (spars_all, ks_all, vs_all) = jax.lax.scan(
+                    body, h,
+                    (jnp.arange(cfg.num_layers), params["layers"],
+                     pool.view.k_codes, pool.view.v_codes,
+                     pool.view.k_scales, pool.view.v_scales))
+            else:
+                ring, outs = (cache.ring_k, cache.ring_v), []
+                for l, kind in enumerate(self._kinds):
+                    lp = jax.tree.map(lambda x: x[l], params["layers"])
+                    i = self._layer_index[l]
+                    if kind == WINDOW:
+                        h, ring = self._window_layer(h, ring, lp, i, start,
+                                                     C, None)
+                        continue
+                    h, out = body(h, (i, lp, *(p[i] for p in pool.view)),
+                                  kind)
+                    outs.append(out)
+                spars_all, ks_all, vs_all = (jnp.stack(a)
+                                             for a in zip(*outs))
+                cache = cache.replace(ring_k=ring[0], ring_v=ring[1])
             sparsity = jnp.mean(spars_all[lstar_arr])
 
             # commit the chunk as C/g TBQ groups, in order — the pool is
@@ -1264,10 +1507,10 @@ class ThinKVEngine:
             # quantize/alloc/budget/refresh sequence as a g-sized arrival
             ngroups = C // dims.G
             kg = jnp.swapaxes(
-                ks_all.reshape(cfg.num_layers, ngroups, dims.G, dims.H,
+                ks_all.reshape(dims.L, ngroups, dims.G, dims.H,
                                cfg.head_dim), 0, 1)
             vg = jnp.swapaxes(
-                vs_all.reshape(cfg.num_layers, ngroups, dims.G, dims.H,
+                vs_all.reshape(dims.L, ngroups, dims.G, dims.H,
                                cfg.head_dim), 0, 1)
 
             def commit(carry, inp):
@@ -1938,8 +2181,7 @@ class ThinKVEngine:
             self.pool = CC.incref_blocks(self.dims, self.pool,
                                          jnp.asarray(hit.table))
             table_i = jnp.asarray(hit.table)
-            cache_i = CC.CTCache(**{f: jnp.asarray(getattr(hit.cache, f))
-                                    for f in CC.CTCache.FIELDS})
+            cache_i = jax.tree.map(jnp.asarray, hit.cache)
             logits = hit.logits
             s0 = hit.length
             self.metrics["prefix_hits"] += 1
@@ -2170,7 +2412,7 @@ class ThinKVEngine:
             self.metrics["dispatches"] += 1
             if self.ticks_per_dispatch == 1:
                 (nxt, self.pool, self.tables, self.caches, _, logits,
-                 alloc_fail, cow_faults, self._slot_rng, pages) = \
+                 alloc_fail, cow_faults, self._slot_rng, counters) = \
                     self._tick(self.params, self.pool, self.tables,
                                self.caches, jnp.asarray(self._feed),
                                jnp.asarray(active), self._slot_rng)
@@ -2182,7 +2424,7 @@ class ThinKVEngine:
                                     lengths=self._slot_ntok.copy(),
                                     logits=logits, alloc_fail=alloc_fail,
                                     cow_faults=cow_faults,
-                                    attn_pages=pages), rng
+                                    counters=counters), rng
             if trips < self.ticks_per_dispatch:
                 self.metrics["early_exit_headroom"] += 1
             R = self.cfg.max_seqs
@@ -2194,7 +2436,7 @@ class ThinKVEngine:
                 if s.request.eos_token is not None:
                     eos[s.idx] = int(s.request.eos_token)
             (toks, valid, logits_buf, self.pool, self.tables, self.caches,
-             self._slot_rng, t, fail, cow, pages) = self._megatick(
+             self._slot_rng, t, fail, cow, counters) = self._megatick(
                 self.params, self.pool, self.tables, self.caches,
                 jnp.asarray(self._feed), jnp.asarray(active),
                 self._slot_rng, jnp.asarray(remaining), jnp.asarray(eos),
@@ -2203,7 +2445,7 @@ class ThinKVEngine:
                                      requested=trips, tokens=toks,
                                      valid=valid, logits=logits_buf,
                                      alloc_fail=fail, cow_faults=cow,
-                                     trips=t, attn_pages=pages), rng
+                                     trips=t, counters=counters), rng
 
     def consume(self, res) -> "ResultTokens | MultiResultTokens":
         """Fold a completed dispatch's deferred device flags into the
@@ -2222,7 +2464,14 @@ class ThinKVEngine:
         to ``metrics["fork_cow_faults"]`` (best-of-n divergence cost).
         ``metrics["attn_pages"]`` adds the mapped pages the dispatch's
         attention read, ``metrics["attn_page_slots"]`` the table entries
-        it spanned (slots x layers x NB per tick)."""
+        it spanned (slots x pool layers x NB per tick).  With window
+        layers ``metrics["window_positions"]`` adds the ring positions
+        their attention read (over window layers and active slots); with
+        experts ``metrics["expert_assignments"]`` adds the routed
+        (token, expert) pairs of active slots that landed on held
+        experts, ``metrics["experts_hit"]`` the held experts with at
+        least one such pair (per layer and tick), and
+        ``metrics["expert_slots"]`` layers x held experts per tick."""
         with TR.span(TR.CONSUME):
             if res.alloc_fail_host:
                 raise AssertionError(
@@ -2233,9 +2482,13 @@ class ThinKVEngine:
             self.metrics["cow_faults"] += int(cow.sum())
             self.metrics["fork_cow_faults"] += int(cow[self._forked].sum())
             trips = res.trips_host if res.packed else 1
-            self.metrics["attn_pages"] += res.attn_pages_host
+            for k, v in res.counters_host.items():
+                self.metrics[k] += v
             self.metrics["attn_page_slots"] += trips * int(
                 np.prod(self.tables.shape))
+            if self.mcfg.moe is not None:
+                self.metrics["expert_slots"] += trips * \
+                    self.mcfg.num_layers * self.mcfg.moe.num_held
             if res.packed:
                 if trips < res.requested:
                     self.metrics["early_exit_finish"] += 1

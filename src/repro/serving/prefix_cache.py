@@ -24,8 +24,10 @@ states:
 * **value** — the per-layer block table at that boundary (logical →
   physical mapping of the committed blocks), a numpy snapshot of the
   request's ``CTCache`` metadata pytree (slot states/bits/segments, TBQ
-  buffer, thought bookkeeping), and the boundary's last-token logits (so
-  an exact full-prompt hit needs no forward pass at all).
+  buffer, thought bookkeeping, and the window layers' rings, which at a
+  boundary hold keys and values of the prefix alone), and the boundary's
+  last-token logits (so an exact full-prompt hit needs no forward pass at
+  all).
 
 Registration INCREFS every mapped block (the cache is a first-class
 reference holder); a hit increfs them again for the admitted request and
@@ -41,6 +43,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -132,8 +135,7 @@ class PrefixCache:
             pool, _ = self.evict_lru(pool)
         entry = PrefixEntry(
             key=key, length=int(n), table=np.asarray(table).copy(),
-            cache=CC.CTCache(**{f: np.asarray(getattr(cache, f)).copy()
-                                for f in CC.CTCache.FIELDS}),
+            cache=jax.tree.map(lambda x: np.asarray(x).copy(), cache),
             logits=np.asarray(logits).copy(), full_only=bool(full_only))
         self._touch(entry)
         self.entries[key] = entry

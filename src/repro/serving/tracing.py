@@ -41,8 +41,14 @@ outside the result fetch; each is counted in ``metrics["host_syncs"]``.
 Scope tree of the tick (``tick`` / ``mega``)::
 
     tick_core/{trunk, attention, sparsity_probe, residual, advance, unembed}
+    tick_core/trunk/moe             the held experts (models with experts)
+    tick_core/window_attention      the rings (models with window layers)
     tick_core/advance/.../{gather_view, commit_evict, refresh, sync_tables}
     sample
+
+``moe`` and ``window_attention`` are phases too (each sets the attribute)
+but only a model with experts or window layers has them; a model without
+either compiles the tick it had before they existed.
 """
 from __future__ import annotations
 
@@ -80,6 +86,10 @@ SAMPLE = "sample"
 #: the phases, each also named by the ``scope`` frontend attribute
 PHASES = (TICK_CORE, TRUNK, ATTENTION, PROBE, RESIDUAL, ADVANCE, UNEMBED,
           SAMPLE)
+
+#: phases only a model with window layers or experts has
+WINDOW_ATTENTION = "window_attention"
+MOE = "moe"
 
 GATHER_VIEW = "gather_view"
 COMMIT_EVICT = "commit_evict"

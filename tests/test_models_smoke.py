@@ -78,6 +78,7 @@ def test_full_config_matches_assignment(arch):
         "falcon-mamba-7b": (64, 4096, 0, 0, 0, 65024),
         "zamba2-7b": (81, 3584, 32, 32, 14336, 32000),
         "r1-llama-8b": (32, 4096, 32, 8, 14336, 128256),
+        "mellum2-12b-a2.5b": (28, 2304, 32, 4, 896, 98304),
     }
     L, d, h, kv, ff, v = table[arch]
     assert cfg.num_layers == L and cfg.d_model == d
@@ -95,6 +96,12 @@ def test_full_config_matches_assignment(arch):
         assert cfg.ssm.state_size == 64
     if arch == "qwen2-7b":
         assert cfg.qkv_bias
+    if arch == "mellum2-12b-a2.5b":
+        assert cfg.moe.num_experts == 64 and cfg.moe.num_held == 64
+        assert cfg.moe.num_experts_per_token == 8
+        assert cfg.sliding_window == 1024 and cfg.qk_norm
+        assert cfg.layer_kinds[:4] == ("window",) * 3 + ("full",) \
+            and len(cfg.window_layers) == 21
 
 
 def test_dense_decode_matches_forward(rng):

@@ -2,6 +2,7 @@
 traces — calibration -> thought classification -> TBQ/TBE/CT serving —
 validated against the paper's own qualitative claims.
 """
+import dataclasses
 import functools
 
 import jax
@@ -119,7 +120,10 @@ def test_proactive_vs_per_step_eviction_rates(rng):
 
 
 def test_engine_with_moe_backbone(rng):
-    cfg = get_smoke_config("mixtral-8x7b")
+    # the engine serves a window only as window layers (layer_kinds) and
+    # refuses mixtral's uniform sliding_window; its full-attention form
+    cfg = dataclasses.replace(get_smoke_config("mixtral-8x7b"),
+                              sliding_window=0)
     tk = ThinKVConfig(refresh_interval=16, group_size=8, block_size=8,
                       token_budget=48, retention_schedule=(16, 8, 4),
                       min_retention=4, max_segments=64, kmeans_iters=4)
